@@ -17,12 +17,21 @@ Spans use ``time.monotonic()`` for start/duration; all daemons of a
 ``MiniCluster`` share one process, so starts are directly comparable
 and ``chrome_trace`` can emit absolute microsecond timestamps for
 chrome://tracing without clock alignment.
+
+``stage(name)`` is the other clock: a ``jax.profiler`` TraceMe around
+one synchronous host stage of the op path (``msgr_send``, ``osd_op``,
+``engine_launch``, ``wal_commit`` ...), so a profiler session's trace
+names what each host thread did beside the device programs.  It costs
+one TraceMe check when no session is active, and nothing at all in a
+process that never imported JAX.
 """
 
 from __future__ import annotations
 
 import collections
+import contextlib
 import random
+import sys
 import threading
 import time
 import uuid
@@ -96,14 +105,24 @@ class Span:
         }
 
 
+_NO_STAGE = contextlib.nullcontext()
+
+
+def stage(name: str):
+    """Context manager marking one synchronous host stage on the
+    profiler's clock (a ``jax.profiler.TraceAnnotation``).  Never hold
+    one across an ``await``: other coroutines run on that thread in
+    between.  JAX is looked up, never imported: a process without it
+    gets a shared no-op context."""
+    annotation = getattr(sys.modules.get("jax.profiler"),
+                         "TraceAnnotation", None)
+    if annotation is None:
+        return _NO_STAGE
+    return annotation(name)
+
+
 class Tracer:
     """Per-daemon collector: bounded ring of finished spans.
-
-    ``perf``, when attached, receives a
-    ``tinc("<layer>_span_duration", dur)`` per finished span keyed by
-    the span's ``layer`` tag — the per-layer time-avg counters the
-    exporter scrapes.  Unknown counter names are ignored so callers
-    can tag freely.
 
     Two throttles keep tracing affordable under load (reference
     head-sampling; ``tracer_sampling_rate`` / ``tracer_span_budget``
@@ -119,12 +138,11 @@ class Tracer:
     MAX_PINNED_TRACES = 32
 
     def __init__(self, daemon: str = "", ring_size: int = 4096,
-                 enabled: bool = False, perf=None,
+                 enabled: bool = False,
                  sampling_rate: float = 1.0, span_budget: int = 0,
                  tail_slow_s: float = 0.0):
         self.daemon = daemon
         self.enabled = bool(enabled)
-        self.perf = perf
         self.sampling_rate = float(sampling_rate)
         self.span_budget = int(span_budget)     # roots/sec; 0 = off
         # tail sampling: a root closing slower than this (or with an
@@ -186,13 +204,6 @@ class Tracer:
                 self._spans.append(span)
                 if span.parent_id is None and self._should_pin(span):
                     self._pin_locked(span.trace_id)
-        perf = self.perf
-        if perf is not None:
-            layer = span.tags.get("layer", "op")
-            try:
-                perf.tinc(f"{layer}_span_duration", span.duration)
-            except KeyError:
-                pass                    # layer without a counter
 
     # -- tail sampling ---------------------------------------------------
 

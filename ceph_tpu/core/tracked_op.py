@@ -2,10 +2,16 @@
 
 Reference behavior re-created (``src/common/TrackedOp.{h,cc}``;
 SURVEY.md §3.1/§6.1): every request entering a daemon is wrapped in a
-tracked op that records event timestamps ("queued", "reached_pg",
-"commit_sent"...); the tracker can dump ops-in-flight, keeps a bounded
-history of completed ops (the `dump_historic_ops` admin command), and
-flags ops alive past a complaint age (slow-op health warnings).
+tracked op that records event timestamps; the tracker can dump
+ops-in-flight, keeps a bounded history of completed ops (the
+`dump_historic_ops` admin command), and flags ops alive past a
+complaint age (slow-op health warnings).
+
+A client op on the OSD is created when the messenger hands it over and
+marks Ceph's event names in order: ``queued_for_pg``, ``reached_pg``,
+``started``, ``encode_submitted``, ``encoded``, ``sub_op_sent``,
+``sub_op_commit_rec``, ``commit_sent``, ``done``.  The OSD's stage
+latency counters are differences of these stamps.
 """
 
 from __future__ import annotations
@@ -28,6 +34,20 @@ class TrackedOp:
         self.events.append((time.monotonic() - self.initiated_at, name))
         if self.span is not None:
             self.span.event(name)
+
+    def event_time(self, name: str, first: bool = False) -> float | None:
+        """Offset (s after ``initiated``) of the latest — or, with
+        ``first``, the earliest — event called ``name``; None if none."""
+        seq = self.events if first else reversed(self.events)
+        return next((t for t, e in seq if e == name), None)
+
+    def interval(self, start: str, end: str) -> float | None:
+        """Seconds from the latest ``start`` to the latest ``end``, or
+        None unless both were marked in that order."""
+        t0, t1 = self.event_time(start), self.event_time(end)
+        if t0 is None or t1 is None or t1 < t0:
+            return None
+        return t1 - t0
 
     def finish(self):
         self.mark_event("done")

@@ -774,6 +774,8 @@ class BatchMapper:
                 np.ascontiguousarray(a).tobytes()).hexdigest()[:16]
 
         return {
+            # the exported program's name (entries without it: run)
+            "program": "crush_map",
             "jax": jax.__version__,
             "backend": jax.default_backend(),
             "ln_mode": self._ln_mode,
@@ -804,7 +806,7 @@ class BatchMapper:
         if this program can't export on this jax."""
         import jax
         global TRACE_COUNT
-        from ..native.aot import CompileCache
+        from ..native.aot import CompileCache, named_call
         cache = CompileCache.default()
         if cache is not None:
             exported = cache.load_exported("crush", self._cache_key())
@@ -825,7 +827,7 @@ class BatchMapper:
                     exported = None
                 if exported is not None:
                     self.chunk = cached
-                    return jax.jit(exported.call), True
+                    return jax.jit(named_call("crush_map", exported)), True
         run = self._build()
         TRACE_COUNT += 1
         if cache is not None:
@@ -837,7 +839,7 @@ class BatchMapper:
                                      exported)
                 # execute through the exported module so cold and warm
                 # processes feed XLA the identical program
-                return jax.jit(exported.call), False
+                return jax.jit(named_call("crush_map", exported)), False
             except Exception:
                 pass  # non-exportable on this jax — plain jit works
         return jax.jit(run), False
@@ -1497,7 +1499,7 @@ class BatchMapper:
             # the same work) — not worth its compile cost
             fn = indep_fn
 
-        def run(x, wdev, ln16, wtab):
+        def crush_map(x, wdev, ln16, wtab):
             # mode chosen at build: "onehot" computes the numerator on
             # device (TPU: gathers are the pathology); "table" uses
             # the passed-in 64Ki gather table (CPU: gathers are fine)
@@ -1511,7 +1513,7 @@ class BatchMapper:
                 res = jnp.concatenate([res, pad], axis=1)
             return res
 
-        return run
+        return crush_map
 
     @staticmethod
     def _split_blocks(steps) -> list[list]:

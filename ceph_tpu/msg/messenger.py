@@ -37,11 +37,13 @@ import json
 import os
 import struct
 import threading
+import time
 import zlib
 from dataclasses import dataclass
 
 from ..core.auth import AuthError, CryptoKey, ServiceVerifier
 from ..core.encoding import DecodeError
+from ..core.tracer import stage
 from .fault import DELAY, DROP, DUP, PARTITION, REORDER, FaultInjector
 from .message import Message
 
@@ -156,7 +158,7 @@ class Connection:
                 # so the scramble is a real logical-order inversion)
                 if span is not None:
                     span.set_tag("hold_s", round(d.hold_s, 6))
-                    span.finish()
+                msg._wire_span = span
                 self.msgr._call_soon(
                     self.msgr._loop.call_later, d.hold_s,
                     self._send_q.put_nowait, msg)
@@ -166,8 +168,8 @@ class Connection:
                 # the session-layer dedup does NOT absorb it and the
                 # application sees a true duplicate delivery
                 self.msgr._call_soon(self._send_q.put_nowait, msg)
-        if span is not None:
-            span.finish()
+        # the span closes once the sender has written the frame
+        msg._wire_span = span
         self.msgr._call_soon(self._send_q.put_nowait, msg)
 
     def mark_down(self):
@@ -188,6 +190,12 @@ class Connection:
         self.msgr._conn_closed(self)
 
     async def _write_frame(self, tag: int, payload: bytes):
+        await self._put_frame(tag, payload).drain()
+
+    def _put_frame(self, tag: int, payload: bytes
+                   ) -> asyncio.StreamWriter:
+        """Build one frame and hand it to the transport (synchronous);
+        → the writer, for the caller to drain."""
         w = self._writer
         if w is None:
             raise ConnectionError("not connected")
@@ -213,7 +221,24 @@ class Connection:
             if self.session_key:
                 frame += self.session_key.sign(payload)
         w.write(frame)
-        await w.drain()
+        return w
+
+    def _put_message(self, msg: Message) -> asyncio.StreamWriter:
+        """Encode one message and write its frame: the synchronous half
+        of a send, timed into the messenger's perf sink."""
+        t0 = time.monotonic()
+        with stage("msgr_send"):
+            payload = msg.encode()
+            w = self._put_frame(TAG_MSG, payload)
+        perf = self.msgr.perf
+        if perf is not None:
+            perf.inc("msgr_send_messages")
+            perf.inc("msgr_send_bytes", len(payload))
+            perf.tinc("msgr_running_send_time", time.monotonic() - t0)
+        span = getattr(msg, "_wire_span", None)
+        if span is not None:
+            span.finish()
+        return w
 
     async def _sender(self, gen: int):
         try:
@@ -222,7 +247,7 @@ class Connection:
                 self.out_seq += 1
                 msg.seq = self.out_seq
                 self._unacked[msg.seq] = msg
-                await self._write_frame(TAG_MSG, msg.encode())
+                await self._put_message(msg).drain()
         except asyncio.CancelledError:
             pass
         except (ConnectionError, OSError):
@@ -253,42 +278,49 @@ class Connection:
                 raise ConnectionError("frame signature mismatch")
         return tag, payload
 
+    def _deliver(self, payload: bytes):
+        """Decode one message frame and dispatch it if it is the next in
+        sequence: the synchronous half of a receive, timed into the
+        messenger's perf sink.  The caller then acks ``in_seq``."""
+        t0 = time.monotonic()
+        with stage("msgr_recv"):
+            try:
+                msg = Message.decode(payload)
+            except ValueError:
+                # unknown message TYPE (version skew): the frame is
+                # CRC-valid, so consume it — take the seq from the
+                # fixed header offset, ack, and drop, exactly so a
+                # newer peer doesn't replay it forever
+                seq = struct.unpack_from("<Q", payload, 2)[0]
+                if seq == self.in_seq + 1:
+                    self.in_seq = seq
+                msg = None
+            if msg is not None and msg.seq == self.in_seq + 1:
+                self.in_seq = msg.seq
+                msg.connection = self
+                self.msgr._dispatch(msg, t0)
+            # else a duplicate (≤ in_seq: replay after a lost ack) or
+            # a GAP (a stale transport's buffered frames racing the
+            # resumed one): drop either; the re-ack of the cumulative
+            # position lets the peer trim/replay correctly
+        perf = self.msgr.perf
+        if perf is not None:
+            perf.inc("msgr_recv_messages")
+            perf.inc("msgr_recv_bytes", len(payload))
+            perf.tinc("msgr_running_recv_time", time.monotonic() - t0)
+
     async def _reader(self, r: asyncio.StreamReader, gen: int):
         try:
             while True:
                 tag, payload = await self._read_frame(r)
                 if tag == TAG_MSG:
-                    try:
-                        msg = Message.decode(payload)
-                    except ValueError:
-                        # unknown message TYPE (version skew): the frame
-                        # is CRC-valid, so consume it — take the seq from
-                        # the fixed header offset, ack, and drop, exactly
-                        # so a newer peer doesn't replay it forever
-                        seq = struct.unpack_from("<Q", payload, 2)[0]
-                        if seq == self.in_seq + 1:
-                            self.in_seq = seq
-                        await self._write_frame(
-                            TAG_ACK, struct.pack("<Q", self.in_seq))
-                        continue
-                    if msg.seq != self.in_seq + 1:
-                        # duplicate (≤ in_seq: replay after a lost ack)
-                        # or a GAP (a stale transport's buffered frames
-                        # racing the resumed one): drop either, and
-                        # RE-ACK the cumulative position so the peer
-                        # trims/replays correctly instead of forever
-                        await self._write_frame(
-                            TAG_ACK, struct.pack("<Q", self.in_seq))
-                        continue
-                    self.in_seq = msg.seq
-                    msg.connection = self
                     # dispatch BEFORE the ack write: the ack await can
                     # raise on a dying transport, and a message whose
                     # in_seq already advanced would then be swallowed —
                     # deliver-then-ack + dedup = exactly-once
-                    self.msgr._dispatch(msg)
+                    self._deliver(payload)
                     await self._write_frame(
-                        TAG_ACK, struct.pack("<Q", msg.seq))
+                        TAG_ACK, struct.pack("<Q", self.in_seq))
                 elif tag == TAG_ACK:
                     (seq,) = struct.unpack("<Q", payload)
                     for s in [s for s in self._unacked if s <= seq]:
@@ -369,7 +401,7 @@ class Connection:
                     msg = self._unacked.get(seq)
                     if msg is None:
                         continue   # acked concurrently by the new reader
-                    await self._write_frame(TAG_MSG, msg.encode())
+                    await self._put_message(msg).drain()
             finally:
                 w.transport.set_write_buffer_limits()
         self._tasks.append(self.msgr._loop.create_task(self._sender(gen)))
@@ -423,6 +455,9 @@ class Messenger:
         # spans are only cut for messages already carrying a trace
         # ctx, so heartbeats/elections stay span-free
         self.tracer = None
+        # the owning daemon's PerfCounters (the msgr_* counters), or
+        # None: frames, payload bytes, send and receive time
+        self.perf = None
         self.dispatchers: list[Dispatcher] = []
         self.connections: list[Connection] = []
         # observability: every EPIPE/ECONNRESET/half-open cut that was
@@ -715,7 +750,9 @@ class Messenger:
         await con._start_io(r, w, hello.get("in_seq", 0))
 
     # -- dispatch ----------------------------------------------------------
-    def _dispatch(self, msg: Message):
+    def _dispatch(self, msg: Message, t_recv: float):
+        """Hand a decoded message to the dispatchers; its wire_recv
+        span starts at ``t_recv``, before the decode."""
         tracer = self.tracer
         span = None
         if tracer is not None and tracer.enabled:
@@ -724,6 +761,8 @@ class Messenger:
                 span = tracer.start_span(
                     f"wire_recv:{type(msg).__name__}", parent=ctx,
                     tags={"layer": "wire"})
+                if span is not None:
+                    span.start = t_recv
         try:
             for d in self.dispatchers:
                 try:

@@ -161,6 +161,16 @@ class CompileCache:
         return len(doomed)
 
 
+def named_call(name: str, exported):
+    """``exported.call`` under ``name``: jit names its program (the HLO
+    module ``jit_<name>``, which a profiler trace shows) after the
+    function it wraps, and a bare ``exported.call`` is ``jit_call``."""
+    def call(*args):
+        return exported.call(*args)
+    call.__name__ = call.__qualname__ = name
+    return call
+
+
 def cached_export(namespace: str, key: dict, make_fn, specs):
     """Export-through-cache: deserialize `namespace`/`key` if present,
     else trace+export ``make_fn()`` (a zero-arg callable returning the

@@ -134,8 +134,11 @@ class GFLinear:
         # XLA-composed paths here
         if self.backend.startswith("pallas") and use_bits is False:
             raise ValueError("pallas backends are bitmatrix-only")
+
+        def gf_linear(data):        # the program's name in a trace
+            return self._apply(data)
         self._fn = (self._apply if self.backend.startswith("pallas")
-                    else jax.jit(self._apply))
+                    else jax.jit(gf_linear))
         # persistent warm start (XLA path only): per input shape, the
         # lowered program round-trips through the export cache exactly
         # like the CRUSH mapper's — a fresh process deserializes
@@ -153,11 +156,12 @@ class GFLinear:
         return fn
 
     def _warm_start(self, shape: tuple):
-        from ..native.aot import CompileCache, cached_export
+        from ..native.aot import CompileCache, cached_export, named_call
         if CompileCache.default() is None:
             return self._fn, False
         import hashlib
-        key = {"kind": "gf_linear", "jax": jax.__version__,
+        # v2: the exported program is named gf_linear (v1: _apply)
+        key = {"kind": "gf_linear/v2", "jax": jax.__version__,
                "x64": bool(jax.config.jax_enable_x64),
                "backend": jax.default_backend(),
                "use_bits": self.use_bits, "m": self.m, "k": self.k,
@@ -165,9 +169,9 @@ class GFLinear:
                "shape": list(shape)}
         try:
             exported, hit = cached_export(
-                "ec", key, lambda: jax.jit(self._apply),
+                "ec", key, lambda: self._fn,
                 (jax.ShapeDtypeStruct(shape, jnp.uint8),))
-            return jax.jit(exported.call), hit
+            return jax.jit(named_call("gf_linear", exported)), hit
         except Exception:
             return self._fn, False
 
@@ -320,16 +324,17 @@ class GFEncodeDigest:
         self.mesh_hits: dict[tuple, bool] = {}
 
     def _make(self, batch: int, length: int):
-        """The program for ``[batch, k, length]``: ``run(data, kt,
-        const_row)``.  The CRC contribution matrix ``kt`` (``[8L, 32]``
-        int8 — 128 MiB at a 512 KiB shard) is an argument, not a
-        closure constant: as a constant it was baked into every
-        program, so each (B, L) compile and each export-cache entry
-        carried it (~10 s and 128 MiB per shape on the CPU backend)."""
+        """The program for ``[batch, k, length]``:
+        ``gf_encode_digest(data, kt, const_row)``.  The CRC contribution
+        matrix ``kt`` (``[8L, 32]`` int8 — 128 MiB at a 512 KiB shard)
+        is an argument, not a closure constant: as a constant it was
+        baked into every program, so each (B, L) compile and each
+        export-cache entry carried it (~10 s and 128 MiB per shape on
+        the CPU backend)."""
         k, m = self.k, self.m
         mat = self._mat
 
-        def run(data, kt, const_row):                     # [B, k, L] u8
+        def gf_encode_digest(data, kt, const_row):        # [B, k, L] u8
             parity = gf_matmul_bits(mat, data, m)         # [B, m, L]
             shards = jnp.concatenate([data, parity], axis=1)
             flat = shards.reshape(batch * (k + m), length)
@@ -345,7 +350,7 @@ class GFEncodeDigest:
                            axis=-1, dtype=jnp.uint32)
             return parity, crcs.reshape(batch, k + m)
 
-        return run
+        return gf_encode_digest
 
     def _digest_consts(self, length: int) -> tuple:
         """(kt, const_row) for one shard length, on the device(s) the
@@ -383,21 +388,22 @@ class GFEncodeDigest:
     def _build(self, shape: tuple) -> tuple:
         """(program, export-cache hit, mesh-sharded) for one shape."""
         batch, _k, length = shape
-        run = self._make(batch, length)
+        program = self._make(batch, length)
         donate = (0,) if self.donate else ()
         if self.mesh is not None and batch % self.mesh.size == 0:
             from jax.sharding import NamedSharding, PartitionSpec
             spec = PartitionSpec(tuple(self.mesh.axis_names), None, None)
             rep = NamedSharding(self.mesh, PartitionSpec())
-            return jax.jit(run, donate_argnums=donate, in_shardings=(
+            return jax.jit(program, donate_argnums=donate, in_shardings=(
                 NamedSharding(self.mesh, spec), rep, rep)), False, True
-        fn, hit = jax.jit(run, donate_argnums=donate), False
-        from ..native.aot import CompileCache, cached_export
+        fn, hit = jax.jit(program, donate_argnums=donate), False
+        from ..native.aot import CompileCache, cached_export, named_call
         if CompileCache.default() is not None:
             import hashlib
-            # v2: the CRC matrices became arguments (v1 entries baked
-            # them in and take one argument)
-            key = {"kind": "gf_encode_digest/v2", "jax": jax.__version__,
+            # v3: the exported program is named gf_encode_digest (v2:
+            # run); v2: the CRC matrices became arguments (v1 entries
+            # baked them in and take one argument)
+            key = {"kind": "gf_encode_digest/v3", "jax": jax.__version__,
                    "x64": bool(jax.config.jax_enable_x64),
                    "backend": jax.default_backend(),
                    "m": self.m, "k": self.k,
@@ -406,11 +412,12 @@ class GFEncodeDigest:
                    "shape": list(shape)}
             try:
                 exported, hit = cached_export(
-                    "ec", key, lambda: jax.jit(run),
+                    "ec", key, lambda: jax.jit(program),
                     (jax.ShapeDtypeStruct(shape, jnp.uint8),
                      jax.ShapeDtypeStruct((8 * length, 32), jnp.int8),
                      jax.ShapeDtypeStruct((32,), jnp.int32)))
-                fn = jax.jit(exported.call, donate_argnums=donate)
+                fn = jax.jit(named_call("gf_encode_digest", exported),
+                             donate_argnums=donate)
             except Exception:
                 pass            # non-exportable on this jax: plain jit
         return fn, hit, False

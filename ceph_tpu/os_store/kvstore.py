@@ -39,6 +39,7 @@ import threading
 import time
 from typing import Callable
 
+from ..core.tracer import stage
 from . import walog
 from .crash import CrashInjector, SimulatedPowerLoss
 from .memstore import MemStore
@@ -469,7 +470,8 @@ class WALStore(MemStore):
                     wal, off = self._wal, self._append_off
             if err is None and wal is not None:
                 try:
-                    os.fsync(wal.fileno())
+                    with stage("wal_commit"):   # the group's one fsync
+                        os.fsync(wal.fileno())
                 except OSError as e:
                     err = StoreError(
                         f"{self.name}: wal fsync failed: {e}")

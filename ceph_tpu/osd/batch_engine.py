@@ -77,6 +77,8 @@ import time
 
 import numpy as np
 
+from ..core.tracer import stage
+
 
 def _next_pow2(n: int) -> int:
     return 1 << max(0, (int(n) - 1).bit_length())
@@ -720,7 +722,8 @@ class BatchEngine:
                 return 0
             prefix = {"recon": "recon_", "comp": "comp_"}.get(lane, "")
             self.stats[f"{prefix}flush_{reason}"] += 1
-            flights = self._dispatch(pending, reason, lane)
+            with stage("engine_launch"):
+                flights = self._dispatch(pending, reason, lane)
             n = len(flights)
             for fl in flights:
                 if use_worker:
@@ -729,7 +732,8 @@ class BatchEngine:
                 else:
                     inline.append(fl)
         for fl in inline:
-            self._complete(fl)
+            with stage("engine_complete"):
+                self._complete(fl)
         if self.on_lane_flush is not None:
             try:
                 self.on_lane_flush(lane, len(pending), staged)
@@ -779,7 +783,8 @@ class BatchEngine:
             try:
                 if fl is None:
                     return
-                self._complete(fl)
+                with stage("engine_complete"):
+                    self._complete(fl)
             finally:
                 self._flights.task_done()
 

@@ -31,6 +31,7 @@ from ..core.config import ConfigProxy
 from ..core.options import build_options
 from ..core.perf_counters import PerfCountersBuilder
 from ..core.threading_utils import SafeTimer
+from ..core.tracer import stage
 from ..core.tracked_op import OpTracker
 from ..mon import messages as MM
 from ..mon.client import MonClient
@@ -78,7 +79,21 @@ def _build_osd_perf(name: str):
     b.add_u64_counter("op", "client operations")
     b.add_u64_counter("op_r", "client read operations")
     b.add_u64_counter("op_w", "client write operations")
-    b.add_time_avg("op_latency", "client op latency")
+    b.add_time_avg("op_latency",
+                   "client op latency, from reached_pg to the reply")
+    # the op's stages, from the TrackedOp's event stamps (reference
+    # l_osd_op_before_dequeue_op_lat; the other two are this OSD's)
+    b.add_time_avg("op_before_dequeue_op_lat",
+                   "client op from dispatch to reached_pg: op queue "
+                   "and daemon-lock wait")
+    b.add_time_avg("op_encode_lat",
+                   "client write from encode_submitted to encoded: "
+                   "batch engine queue, flush, device, completion")
+    b.add_time_avg("op_subop_commit_lat",
+                   "client write from sub_op_sent to the last peer's "
+                   "sub_op_commit_rec")
+    b.add_u64_counter("op_resent",
+                      "client ops that arrived with retry_attempt > 0")
     b.add_u64_counter("subop", "replica/shard sub-operations")
     b.add_u64_counter("recovery_ops", "objects recovered/pushed")
     b.add_u64_counter("scrub_errors_found", "scrub inconsistencies")
@@ -93,12 +108,6 @@ def _build_osd_perf(name: str):
     b.add_u64_counter("scrubs_scheduled",
                       "periodic scrubs started by the tick")
     b.add_u64("numpg", "placement groups hosted")
-    # per-layer span durations (tracer perf sink; ceph_*_span_duration
-    # in the exporter) — zero until jaeger_tracing_enable is on
-    b.add_time_avg("osd_span_duration", "OSD op span duration")
-    b.add_time_avg("wire_span_duration", "messenger wire span duration")
-    b.add_time_avg("device_span_duration",
-                   "TPU device kernel span duration")
     # log2 op-latency distribution in microseconds (reference
     # osd_op_latency histograms; `perf histogram dump`)
     b.add_histogram("op_latency_histogram",
@@ -115,6 +124,18 @@ def _build_osd_perf(name: str):
     b.add_u64_counter("device_bytes_out", "bytes fetched from device")
     b.add_histogram("device_launch_hist",
                     "launch wall time distribution (us, log2 buckets)")
+    # the messenger's perf sink (reference AsyncMessenger l_msgr_*):
+    # message frames and their payload bytes, and the synchronous time
+    # spent encoding + writing / decoding + dispatching them
+    b.add_u64_counter("msgr_send_messages", "messages sent")
+    b.add_u64_counter("msgr_recv_messages", "messages received")
+    b.add_u64_counter("msgr_send_bytes", "message frame payload bytes sent")
+    b.add_u64_counter("msgr_recv_bytes",
+                      "message frame payload bytes received")
+    b.add_time_avg("msgr_running_send_time",
+                   "message encode, frame build and socket write")
+    b.add_time_avg("msgr_running_recv_time",
+                   "message decode and dispatch")
     return b.create_perf_counters()
 
 
@@ -153,14 +174,12 @@ class OSDaemon(Dispatcher):
             "osd_op_history_duration",
             lambda _n, v: setattr(self.op_tracker, "history_duration",
                                   float(v)))
-        # op tracing: spans adopted from the client ctx riding MOSDOp;
-        # the perf sink feeds the *_span_duration counters above
+        # op tracing: spans adopted from the client ctx riding MOSDOp
         from ..core.tracer import Tracer
         self.tracer = Tracer(
             daemon=f"osd.{whoami}",
             ring_size=int(self.config.get("tracer_ring_size") or 4096),
             enabled=bool(self.config.get("jaeger_tracing_enable")),
-            perf=self.perf,
             sampling_rate=float(
                 self.config.get("tracer_sampling_rate") or 1.0),
             span_budget=int(
@@ -359,6 +378,7 @@ class OSDaemon(Dispatcher):
                     "*", "*", **{_k: float(v)}))
         self.msgr.add_dispatcher(self)
         self.msgr.tracer = self.tracer
+        self.msgr.perf = self.perf
         self.monc = MonClient(monmap, entity=f"osd.{whoami}",
                               auth=auth)
         # cluster log: ring + batched MLog uplink, flushed on the tick
@@ -754,9 +774,7 @@ class OSDaemon(Dispatcher):
             except Exception:       # noqa: BLE001 — a poisoned op
                 # must not kill the op thread; fail the op visibly
                 # instead of leaving the client to time out
-                tracked = getattr(msg, "tracked", None)
-                if tracked is not None:
-                    tracked.finish()
+                PG.finish_tracked(msg, "op_faulted")
                 con = getattr(msg, "connection", None)
                 if isinstance(msg, M.MOSDOp) and con is not None:
                     try:
@@ -788,6 +806,10 @@ class OSDaemon(Dispatcher):
             self._tier_client = None
         self.monc.shutdown()
         self.msgr.shutdown()
+        # client ops still queued never reach a PG: finish them, or the
+        # tracker counts them in flight forever
+        while (got := self.op_queue.dequeue(timeout=0)) is not None:
+            PG.finish_tracked(got[1], "shutdown")
         if self.flight_recorder is not None:
             try:
                 self._blackbox_snap()
@@ -1174,11 +1196,14 @@ class OSDaemon(Dispatcher):
         now = time.monotonic()
         m = self.osdmap
         for o in self._hb_peers():
-            self._hb_last.setdefault(o, now)
+            # read once: a map advance on another thread pops the
+            # entry of a peer that came up, and a KeyError here would
+            # end this OSD's tick for good (no more peering retries)
+            last = self._hb_last.setdefault(o, now)
             self.send_to_osd(o, M.MOSDPing(
                 from_osd=self.whoami, epoch=m.epoch, kind="ping",
                 stamp=now))
-            if (now - self._hb_last[o] > self._hb_grace and m.is_up(o)
+            if (now - last > self._hb_grace and m.is_up(o)
                     and now - self._hb_reported.get(o, 0.0)
                     > self._hb_grace):
                 # RE-send while the map still shows the peer up: a
@@ -1442,6 +1467,13 @@ class OSDaemon(Dispatcher):
         klass = _SCHED_CLASS.get(type(msg))
         if klass is None:
             return False
+        if isinstance(msg, M.MOSDOp):
+            # the op's clock starts at receive (reference
+            # OSD::ms_fast_dispatch → op_tracker.create_request)
+            self._track_client_op(msg)
+            if (msg.retry_attempt or 0) > 0:
+                self.perf.inc("op_resent")
+            msg.tracked.mark_event("queued_for_pg")
         dmc = getattr(msg, "dmc", None)
         if klass == CLIENT and isinstance(dmc, dict):
             # distributed dmclock: per-client tags advanced by the
@@ -1475,9 +1507,7 @@ class OSDaemon(Dispatcher):
                 # dead backing store: client ops fail fast with EIO
                 # rather than acking writes that can never commit
                 if isinstance(msg, M.MOSDOp):
-                    tracked = getattr(msg, "tracked", None)
-                    if tracked is not None:
-                        tracked.finish()
+                    PG.finish_tracked(msg, "objectstore_error")
                     if msg.connection is not None:
                         try:
                             msg.connection.send_message(M.MOSDOpReply(
@@ -1491,7 +1521,11 @@ class OSDaemon(Dispatcher):
                             pass
                     return True
             if isinstance(msg, M.MOSDOp):
-                self._handle_client_op(msg)
+                with stage("osd_op"):
+                    if getattr(msg, "tracked", None) is None:
+                        self._track_client_op(msg)  # internal re-entry
+                    msg.tracked.mark_event("reached_pg")
+                    self._handle_client_op(msg)
                 return True
             handlers = {
                 M.MOSDPGQuery: lambda pg: pg.handle_query(msg),
@@ -1614,9 +1648,20 @@ class OSDaemon(Dispatcher):
             return None
         return pg
 
+    def _track_client_op(self, msg: M.MOSDOp):
+        """Attach the op's TrackedOp and, when tracing is on, its span
+        adopted from the client's trace ctx: every mark_event on the
+        tracked op becomes a span event, finish() closes the span."""
+        kinds = {op.get("op") for op in (msg.ops or [])}
+        msg.tracked = self.op_tracker.create_request(
+            f"osd_op({msg.client}.{msg.tid} {msg.pgid} {msg.oid} "
+            f"{'+'.join(sorted(k for k in kinds if k))})")
+        msg.tracked.span = self.tracer.start_span(
+            f"osd_op:{msg.oid}", parent=getattr(msg, "trace", None),
+            tags={"layer": "osd", "pgid": msg.pgid,
+                  "write": bool(kinds & _WRITE_OPS)})
+
     def _handle_client_op(self, msg: M.MOSDOp):
-        # TrackedOp + counters on the op path (reference
-        # OSD::ms_fast_dispatch → op_tracker.create_request)
         kinds = {op.get("op") for op in (msg.ops or [])}
         is_write = bool(kinds & _WRITE_OPS)
         self.perf.inc("op")
@@ -1631,19 +1676,9 @@ class OSDaemon(Dispatcher):
             # account ops + latency only; write bytes are what the
             # heavy-hitter byte ranking attributes)
             msg._acct_bytes = in_bytes
-        msg.tracked = self.op_tracker.create_request(
-            f"osd_op({msg.client}.{msg.tid} {msg.pgid} {msg.oid} "
-            f"{'+'.join(sorted(k for k in kinds if k))})")
-        # adopt the client's trace ctx: every mark_event on the
-        # tracked op becomes a span event, finish() closes the span
-        msg.tracked.span = self.tracer.start_span(
-            f"osd_op:{msg.oid}", parent=getattr(msg, "trace", None),
-            tags={"layer": "osd", "pgid": msg.pgid,
-                  "write": is_write})
         pg = self.pgs.get(PGid.parse(msg.pgid))
         if pg is None:
-            msg.tracked.finish()
-            msg.tracked = None
+            PG.finish_tracked(msg, "pg_gone")
             try:
                 msg.connection.send_message(M.MOSDOpReply(
                     tid=msg.tid, rc=-11, outs="pg not here",

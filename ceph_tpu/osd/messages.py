@@ -33,10 +33,12 @@ class MOSDOp(_JsonMessage):
     ``qos_client``: optional tenant/uid QoS tag (reference the rgw
     user riding req_state) — when set, the mClock scheduler keys its
     per-client streams by tenant instead of the wire entity, so
-    noisy-neighbor isolation is per-tenant, not per-connection."""
+    noisy-neighbor isolation is per-tenant, not per-connection.
+    ``retry_attempt``: how many times the client sent this op before
+    (reference MOSDOp::retry_attempt; None from older senders)."""
     TYPE = 40
     FIELDS = ("tid", "client", "pgid", "oid", "epoch", "ops", "flags",
-              "snapc", "dmc", "trace", "qos_client")
+              "snapc", "dmc", "trace", "qos_client", "retry_attempt")
 
 
 @register_message

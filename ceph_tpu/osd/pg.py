@@ -40,6 +40,7 @@ import time
 
 import numpy as np
 
+from ..core.tracer import stage
 from ..crush.map import CRUSH_ITEM_NONE
 from ..ec.interface import ECProfile
 from ..ec.registry import create_erasure_code
@@ -51,6 +52,11 @@ from . import messages as M
 from .types import (DELETE, LogEntry, MODIFY, PGInfo, PGLog, ZERO)
 
 META_OID = "_meta"          # per-PG meta object (info+log in omap)
+# (counter, start event, end event): a client write's stages, from its
+# TrackedOp's stamps, fed at the reply beside op_latency
+_OP_STAGE_LATS = (("op_encode_lat", "encode_submitted", "encoded"),
+                  ("op_subop_commit_lat", "sub_op_sent",
+                   "sub_op_commit_rec"))
 SNAPMAP_OID = "_snapmapper"  # snap id → clone index (reference SnapMapper)
 _SNAP_SEP = "\x00snap\x00"   # head oid + sep + seq = clone object name
 
@@ -1158,6 +1164,7 @@ class PG:
             # _maybe_finish_scrub / check_scrub_timeout
             self.waiting_for_active.append(lambda: self.do_op(msg))
             return
+        self.mark_tracked(msg, "started")
         try:
             if is_write:
                 self.backend.submit_write(msg, reqid)
@@ -1212,6 +1219,13 @@ class PG:
                 pass    # client re-targets on its next map instead
 
     @staticmethod
+    def mark_tracked(msg, event: str):
+        """Mark an event on a message's TrackedOp, if it has one."""
+        tracked = getattr(msg, "tracked", None)
+        if tracked is not None:
+            tracked.mark_event(event)
+
+    @staticmethod
     def finish_tracked(msg, event: str):
         """Finish a message's TrackedOp (idempotent).  Every path
         that stops working on an op — reply, backoff handoff,
@@ -1238,15 +1252,26 @@ class PG:
         span = getattr(getattr(msg, "tracked", None), "span", None)
         trace = span.ctx() if span is not None \
             else getattr(msg, "trace", None)
-        tracked = self.finish_tracked(msg, "replied")
+        tracked = self.finish_tracked(msg, "commit_sent")
         if tracked is not None:
-            self.daemon.perf.tinc("op_latency", tracked.age)
+            perf = self.daemon.perf
+            # op_latency runs from reached_pg; the wait before it is
+            # its own counter, and the write's stages come from the
+            # same stamps
+            reached = tracked.event_time("reached_pg", first=True) or 0.0
+            latency = tracked.age - reached
+            perf.tinc("op_latency", latency)
+            perf.tinc("op_before_dequeue_op_lat", reached)
+            for counter, start, end in _OP_STAGE_LATS:
+                dt = tracked.interval(start, end)
+                if dt is not None:
+                    perf.tinc(counter, dt)
             # log2 distribution in µs (perf histogram dump / exporter);
             # the span's trace id rides along as the per-bucket
             # slowest-op exemplar (OpenMetrics `_bucket` # {...})
             try:
-                self.daemon.perf.hinc(
-                    "op_latency_histogram", tracked.age * 1e6,
+                perf.hinc(
+                    "op_latency_histogram", latency * 1e6,
                     trace_id=span.trace_id if span is not None
                     else None)
             except KeyError:
@@ -1261,7 +1286,7 @@ class PG:
                             or getattr(msg, "client", None) or "?"),
                     pool=str(self.pgid.pool), pg=str(self.pgid),
                     nbytes=int(getattr(msg, "_acct_bytes", 0)),
-                    lat_s=tracked.age)
+                    lat_s=latency)
         try:
             msg.connection.send_message(M.MOSDOpReply(
                 tid=msg.tid, rc=rc, outs=outs, results=results,
@@ -1725,6 +1750,8 @@ class ReplicatedBackend(PGBackendBase):
                 version=list(version),
                 log_entries=[entry.to_dict()],
                 pg_info=pg.info.to_dict(), trace=trace))
+        if peers:
+            pg.mark_tracked(msg, "sub_op_sent")
         daemon.store.queue_transaction(txn, self._local_commit_cb(reqid))
 
     def _local_commit_cb(self, reqid: str):
@@ -1928,6 +1955,8 @@ class ReplicatedBackend(PGBackendBase):
                 version=list(version),
                 log_entries=[entry.to_dict()],
                 pg_info=pg.info.to_dict(), trace=trace))
+        if peers:
+            pg.mark_tracked(msg, "sub_op_sent")
         daemon.store.queue_transaction(txn, self._local_commit_cb(reqid))
         # gate drops once the local (primary) apply is queued —
         # replicated primaries apply immediately, so the next queued
@@ -2100,7 +2129,11 @@ class ReplicatedBackend(PGBackendBase):
         st = self._inflight.get(msg.reqid)
         if st is None:
             return
-        st["waiting"].discard(msg.from_osd)
+        waiting = st["waiting"]
+        if msg.from_osd in waiting:
+            waiting.discard(msg.from_osd)
+            if not waiting - {_LOCAL_COMMIT}:
+                self.pg.mark_tracked(st["msg"], "sub_op_commit_rec")
         self._maybe_ack(msg.reqid)
 
     # -- replica apply -----------------------------------------------------
@@ -2564,6 +2597,7 @@ class ECBackend(PGBackendBase):
             # resend would queue behind the RMW gate and APPLY AGAIN
             # (double append).  Drop it — the original replies with
             # the same tid (reference: in-progress repop dup check).
+            pg.finish_tracked(msg, "dup_in_flight")
             return
         active.add(reqid)
         if oid in self._rmw:
@@ -2728,6 +2762,7 @@ class ECBackend(PGBackendBase):
 
             def _encoded(comp, _extra, _dlen=len(data)):
                 with daemon.lock:
+                    pg.mark_tracked(msg, "encoded")
                     if span is not None:
                         if comp.info:
                             span.set_tag("batch_rows",
@@ -2751,6 +2786,7 @@ class ECBackend(PGBackendBase):
                         _fail(e)
 
             def _encode(payload, extra):
+                pg.mark_tracked(msg, "encode_submitted")
                 with daemon.profiler.bind():
                     daemon.batch_engine.submit_encode(
                         self.engine, payload, span=span,
@@ -2847,6 +2883,8 @@ class ECBackend(PGBackendBase):
                 version=list(version),
                 log_entries=[entry.to_dict()],
                 pg_info=pg.info.to_dict(), trace=trace))
+        if remote:
+            pg.mark_tracked(msg, "sub_op_sent")
         self._maybe_ack(reqid)
 
     def _shard_txn(self, shard: int, oid: str, chunks, delete: bool,
@@ -2903,28 +2941,35 @@ class ECBackend(PGBackendBase):
         pg.daemon.store.queue_transaction(txn, on_commit)
 
     def apply_sub_write(self, msg: M.MOSDECSubOpWrite):
-        pg, daemon = self.pg, self.pg.daemon
-        daemon.perf.inc("subop")
-        txn = Transaction.from_dict(msg.txn)
-        entries = [LogEntry.from_dict(e) for e in msg.log_entries or []]
-        reply = M.MOSDECSubOpWriteReply(
-            reqid=msg.reqid, pgid=msg.pgid, shard=msg.shard,
-            epoch=daemon.osdmap.epoch, rc=0, from_osd=daemon.whoami)
+        with stage("osd_subop"):
+            pg, daemon = self.pg, self.pg.daemon
+            daemon.perf.inc("subop")
+            txn = Transaction.from_dict(msg.txn)
+            entries = [LogEntry.from_dict(e)
+                       for e in msg.log_entries or []]
+            reply = M.MOSDECSubOpWriteReply(
+                reqid=msg.reqid, pgid=msg.pgid, shard=msg.shard,
+                epoch=daemon.osdmap.epoch, rc=0, from_osd=daemon.whoami)
 
-        def _committed():
-            # the shard ack is a commit promise: it leaves only after
-            # the sub-write is WAL-durable on this OSD
-            with daemon.lock:
-                daemon.send_to_osd(pg.primary, reply)
-        self._apply_shard_txn(txn, entries, _committed)
-        pg._note_local_object_write()
+            def _committed():
+                # the shard ack is a commit promise: it leaves only
+                # after the sub-write is WAL-durable on this OSD
+                with daemon.lock:
+                    daemon.send_to_osd(pg.primary, reply)
+            self._apply_shard_txn(txn, entries, _committed)
+            pg._note_local_object_write()
 
     def handle_sub_write_reply(self, msg: M.MOSDECSubOpWriteReply):
-        st = self._inflight.get(msg.reqid)
-        if st is None:
-            return
-        st["waiting"].discard(msg.shard)
-        self._maybe_ack(msg.reqid)
+        with stage("osd_subop"):
+            st = self._inflight.get(msg.reqid)
+            if st is None:
+                return
+            waiting = st["waiting"]
+            if msg.shard in waiting:
+                waiting.discard(msg.shard)
+                if not waiting:
+                    self.pg.mark_tracked(st["msg"], "sub_op_commit_rec")
+            self._maybe_ack(msg.reqid)
 
     def _maybe_ack(self, reqid: str):
         st = self._inflight.get(reqid)

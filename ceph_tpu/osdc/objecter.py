@@ -21,7 +21,7 @@ import random
 import threading
 import time
 
-from ..core.tracer import Tracer
+from ..core.tracer import Tracer, stage
 from ..mon.client import MonClient
 from ..msg import Dispatcher, EntityAddr, Messenger
 from ..osd import messages as M
@@ -330,47 +330,49 @@ class Objecter(Dispatcher):
         return pool
 
     def _send_op(self, op: _Op):
-        # the CRUSH mapping itself is a traced child: per-send so
-        # resends show their (possibly new) target computation
-        cspan = None if op.span is None else self.tracer.start_span(
-            "crush_map", parent=op.span, tags={"layer": "crush"})
-        pgid, primary = self._calc_target(
-            self._effective_pool(op.pool, op.direct), op.oid)
-        if cspan is not None:
-            cspan.set_tag("pgid", str(pgid))
-            cspan.set_tag("primary", primary)
-            cspan.finish()
-        op.pgid, op.target_osd = pgid, primary
-        if primary >= 0 and self.backoffs.blocked(primary, pgid):
-            if op.span is not None:
-                op.span.event("backoff_parked")
-            return   # parked: released by unblock / map advance
-        op.attempts += 1
-        if op.span is not None and op.attempts > 1:
-            op.span.event(f"resend:{op.attempts - 1}")
-        if primary < 0:
-            return   # no primary this epoch: wait for the next map
-        con = self._osd_con(primary)
-        if con is None:
-            return
-        pool = self.osdmap.pools.get(op.pool)
-        snapc = None
-        if pool is not None and pool.snap_seq:
-            snapc = {"seq": pool.snap_seq,
-                     "snaps": sorted(pool.snaps, reverse=True)}
-        st, sr = self._dmc_osd_snap.get(primary, (0, 0))
-        dmc = {"delta": max(1, self._dmc_total - st),
-               "rho": max(1, self._dmc_res - sr)}
-        self._dmc_osd_snap[primary] = (self._dmc_total, self._dmc_res)
-        try:
-            con.send_message(M.MOSDOp(
-                tid=op.tid, client=self.entity, pgid=str(pgid),
-                oid=op.oid, epoch=self.osdmap.epoch, ops=op.ops,
-                flags=0, snapc=snapc, dmc=dmc,
-                qos_client=op.qos_client,
-                trace=None if op.span is None else op.span.ctx()))
-        except ConnectionError:
-            self._osd_cons.pop(primary, None)
+        with stage("objecter_send"):
+            # the CRUSH mapping itself is a traced child: per-send so
+            # resends show their (possibly new) target computation
+            cspan = None if op.span is None else self.tracer.start_span(
+                "crush_map", parent=op.span, tags={"layer": "crush"})
+            pgid, primary = self._calc_target(
+                self._effective_pool(op.pool, op.direct), op.oid)
+            if cspan is not None:
+                cspan.set_tag("pgid", str(pgid))
+                cspan.set_tag("primary", primary)
+                cspan.finish()
+            op.pgid, op.target_osd = pgid, primary
+            if primary >= 0 and self.backoffs.blocked(primary, pgid):
+                if op.span is not None:
+                    op.span.event("backoff_parked")
+                return   # parked: released by unblock / map advance
+            op.attempts += 1
+            if op.span is not None and op.attempts > 1:
+                op.span.event(f"resend:{op.attempts - 1}")
+            if primary < 0:
+                return   # no primary this epoch: wait for the next map
+            con = self._osd_con(primary)
+            if con is None:
+                return
+            pool = self.osdmap.pools.get(op.pool)
+            snapc = None
+            if pool is not None and pool.snap_seq:
+                snapc = {"seq": pool.snap_seq,
+                         "snaps": sorted(pool.snaps, reverse=True)}
+            st, sr = self._dmc_osd_snap.get(primary, (0, 0))
+            dmc = {"delta": max(1, self._dmc_total - st),
+                   "rho": max(1, self._dmc_res - sr)}
+            self._dmc_osd_snap[primary] = (self._dmc_total, self._dmc_res)
+            try:
+                con.send_message(M.MOSDOp(
+                    tid=op.tid, client=self.entity, pgid=str(pgid),
+                    oid=op.oid, epoch=self.osdmap.epoch, ops=op.ops,
+                    flags=0, snapc=snapc, dmc=dmc,
+                    qos_client=op.qos_client,
+                    trace=None if op.span is None else op.span.ctx(),
+                    retry_attempt=op.attempts - 1))
+            except ConnectionError:
+                self._osd_cons.pop(primary, None)
 
     def _osd_con(self, osd: int):
         addr_s = self.osdmap.osd_addrs.get(osd)

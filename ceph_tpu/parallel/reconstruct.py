@@ -185,7 +185,7 @@ class ShardedEC:
                                     jax.lax.bitwise_xor, dimensions=(0,))
             return parity  # [Bl, m, C] replicated over shard
 
-        def fn(data):  # [B, k_pad, C] sharded P('dp','shard',None)
+        def sharded_encode(data):  # [B, k_pad, C], P('dp','shard',None)
             # out is replicated over 'shard' by construction (all_gather +
             # full XOR-reduce); the static VMA check can't see that.
             # Traced under x64=False: every dtype here is explicit, and
@@ -197,7 +197,7 @@ class ShardedEC:
                     in_specs=P("dp", "shard", None),
                     out_specs=P("dp", None, None), check_vma=False)(data)
 
-        return fn
+        return sharded_encode
 
     def pad_data(self, data: np.ndarray) -> np.ndarray:
         """[B, k, C] -> [B, k_pad, C] zero-padded (payload dtype kept:
@@ -290,7 +290,7 @@ class ShardedEC:
                 data = gf_matmul_bits(pbits, surv, nrows)
             return data
 
-        def fn(chunks):  # [B, n_pad, C] sharded P('dp','shard',None)
+        def sharded_reconstruct(chunks):  # [B, n_pad, C], sharded as data
             # replicated over 'shard' by construction (decode after
             # gather); x64=False at trace time — see _build_encode
             with jax.enable_x64(False):
@@ -299,7 +299,7 @@ class ShardedEC:
                     in_specs=P("dp", "shard", None),
                     out_specs=P("dp", None, None), check_vma=False)(chunks)
 
-        return jax.jit(fn)
+        return jax.jit(sharded_reconstruct)
 
     def reconstruct(self, chunks_padded, erasures: tuple[int, ...],
                     emit: str = "data") -> jax.Array:

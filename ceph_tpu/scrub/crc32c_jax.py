@@ -303,7 +303,7 @@ def _batch_program(length: int, mesh=None):
     import jax
     import jax.numpy as jnp
 
-    def run(data, seeds, kt, at, const_row):
+    def crc32c_batch(data, seeds, kt, at, const_row):
         n = data.shape[0]
         shifts = jnp.arange(8, dtype=jnp.uint8)
         bits = ((data[:, :, None] >> shifts) & jnp.uint8(1))
@@ -321,13 +321,14 @@ def _batch_program(length: int, mesh=None):
                        axis=-1, dtype=jnp.uint32)
 
     if mesh is None:
-        return jax.jit(run)
+        return jax.jit(crc32c_batch)
     from jax.sharding import NamedSharding, PartitionSpec
     axes = tuple(mesh.axis_names)
     rows2d = NamedSharding(mesh, PartitionSpec(axes, None))
     rows1d = NamedSharding(mesh, PartitionSpec(axes))
     rep = NamedSharding(mesh, PartitionSpec())
-    return jax.jit(run, in_shardings=(rows2d, rows1d, rep, rep, rep),
+    return jax.jit(crc32c_batch,
+                   in_shardings=(rows2d, rows1d, rep, rep, rep),
                    out_shardings=rows1d)
 
 

@@ -213,20 +213,6 @@ class TestAdminSurface:
             total += sum(sum(row) for row in hist["values"])
         assert total > 0    # some OSD served a client op
 
-    def test_span_duration_perf_counters(self, cluster):
-        c, r = cluster
-        io = r.open_ioctx("tre")
-        io.write_full("perf-obj", b"w" * 256)
-        time.sleep(0.2)
-        dumps = [admin_command(o.admin_socket.path, "perf dump")
-                 [f"osd.{i}"] for i, o in c.osds.items()]
-        assert any(d["osd_span_duration"]["avgcount"] > 0
-                   for d in dumps)
-        assert any(d["device_span_duration"]["avgcount"] > 0
-                   for d in dumps)
-        assert any(d["wire_span_duration"]["avgcount"] > 0
-                   for d in dumps)
-
 
 class TestTracerUnit:
     def test_disabled_start_span_returns_none(self):
