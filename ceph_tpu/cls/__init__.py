@@ -50,14 +50,14 @@ class ClsContext:
     # -- staged writes ------------------------------------------------------
     def set_xattr(self, name: str, value: bytes):
         self.staged_ops.append({"op": "setxattr", "name": name,
-                                "data": value.hex()})
+                                "data": bytes(value)})
 
     def rm_xattr(self, name: str):
         self.staged_ops.append({"op": "rmxattr", "name": name})
 
     def set_omap(self, kv: dict[str, bytes]):
         self.staged_ops.append({"op": "omap_set", "kv": {
-            k: v.hex() for k, v in kv.items()}})
+            k: bytes(v) for k, v in kv.items()}})
 
     def rm_omap(self, keys: list[str]):
         self.staged_ops.append({"op": "omap_rm", "keys": list(keys)})
@@ -65,7 +65,7 @@ class ClsContext:
     def create(self):
         """Ensure the object exists (zero-length write)."""
         if not self.exists():
-            self.staged_ops.append({"op": "write_full", "data": ""})
+            self.staged_ops.append({"op": "write_full", "data": b""})
 
 
 _REGISTRY: dict[str, dict[str, object]] = {}

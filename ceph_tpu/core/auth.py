@@ -97,9 +97,13 @@ class CryptoKey:
         except Exception as e:
             raise AuthError(f"decrypt failed: {e}") from e
 
-    def sign(self, data: bytes) -> bytes:
-        """8-byte message signature (msgr frame signing)."""
-        return hmac.new(self.secret, data, hashlib.sha256).digest()[:8]
+    def sign(self, *chunks) -> bytes:
+        """8-byte message signature (msgr frame signing) over the
+        concatenation of ``chunks``."""
+        mac = hmac.new(self.secret, digestmod=hashlib.sha256)
+        for c in chunks:
+            mac.update(c)
+        return mac.digest()[:8]
 
     def verify(self, data: bytes, sig: bytes) -> bool:
         return hmac.compare_digest(self.sign(data), sig)

@@ -13,7 +13,10 @@ discipline (SURVEY.md §3.1):
   struct layouts translate mechanically.
 
 `Encoder`/`Decoder` wrap a `BufferList`; ``struct_block`` is the
-ENCODE_START/FINISH pair as a context manager.
+ENCODE_START/FINISH pair as a context manager.  ``Encoder.blob_ref``
+appends a blob without copying it in: ``parts()`` hands the encoding
+back as a list of buffers with each such blob its own element (the
+reference's bufferlist of data segments after a message front).
 """
 
 from __future__ import annotations
@@ -31,6 +34,10 @@ class DecodeError(Exception):
 class Encoder:
     def __init__(self):
         self._out = bytearray()
+        # blobs kept out of _out: (offset in _out, bytes), in order
+        self._refs: list[tuple[int, bytes]] = []
+        self._ref_len = 0
+        self._compat_pos: list[int] = []   # open struct blocks
 
     # -- scalars (little-endian, fixed width) ------------------------------
     def u8(self, v: int):
@@ -69,6 +76,15 @@ class Encoder:
     def raw(self, data):
         self._out += bytes(data)
 
+    def blob_ref(self, data):
+        """A length-prefixed blob whose bytes stay a buffer of their own
+        (see ``parts``); a mutable buffer is snapshotted."""
+        b = data if isinstance(data, bytes) else bytes(data)
+        self.u32(len(b))
+        if b:
+            self._refs.append((len(self._out), b))
+            self._ref_len += len(b)
+
     # -- containers --------------------------------------------------------
     def list_of(self, items, enc_item):
         self.u32(len(items))
@@ -85,25 +101,53 @@ class Encoder:
     @contextlib.contextmanager
     def struct_block(self, version: int, compat: int):
         self.u8(version)
+        self._compat_pos.append(len(self._out))
         self.u8(compat)
         len_pos = len(self._out)
+        ref_len = self._ref_len
         self.u32(0)  # placeholder
         yield self
-        payload = len(self._out) - len_pos - 4
+        self._compat_pos.pop()
+        payload = (len(self._out) - len_pos - 4) + (self._ref_len - ref_len)
         self._out[len_pos:len_pos + 4] = struct.pack("<I", payload)
 
+    def require_compat(self, compat: int):
+        """Raise the innermost open struct's compat: what was written
+        needs a decoder that understands at least this version."""
+        pos = self._compat_pos[-1]
+        self._out[pos] = max(self._out[pos], compat)
+
     # -- output ------------------------------------------------------------
+    def parts(self) -> list:
+        """The encoding as buffers whose concatenation is ``bytes(self)``:
+        every ``blob_ref`` blob is an element of its own, uncopied."""
+        out, pos = [], 0
+        view = memoryview(self._out)
+        for off, b in self._refs:
+            if off > pos:
+                out.append(bytes(view[pos:off]))
+            out.append(b)
+            pos = off
+        if pos < len(self._out) or not out:
+            out.append(bytes(view[pos:]))
+        view.release()
+        return out
+
     def bl(self) -> BufferList:
-        return BufferList(bytes(self._out))
+        return BufferList(bytes(self))
 
     def __bytes__(self) -> bytes:
-        return bytes(self._out)
+        if not self._refs:
+            return bytes(self._out)
+        return b"".join(self.parts())
 
 
 class Decoder:
     def __init__(self, data):
         if isinstance(data, BufferList) and data.num_buffers == 1:
             self._mv = data._ptrs[0].view()  # zero-copy single segment
+        elif isinstance(data, (bytes, memoryview)):
+            self._mv = memoryview(data)      # a received frame, uncopied
         else:
             self._mv = memoryview(bytes(data))
         self._pos = 0

@@ -6,30 +6,11 @@ the MDS."""
 
 from __future__ import annotations
 
-import json
-
-from ..msg.message import Message, register_message
-
-
-class _JsonMessage(Message):
-    FIELDS: tuple = ()
-
-    def __init__(self, **kw):
-        super().__init__()
-        for f in self.FIELDS:
-            setattr(self, f, kw.get(f))
-
-    def encode_payload(self, enc):
-        enc.string(json.dumps({f: getattr(self, f) for f in self.FIELDS}))
-
-    def decode_payload(self, dec, version):
-        data = json.loads(dec.string())
-        for f in self.FIELDS:
-            setattr(self, f, data.get(f))
+from ..msg.message import JsonMessage, register_message
 
 
 @register_message
-class MClientSession(_JsonMessage):
+class MClientSession(JsonMessage):
     """Session open/close handshake (reference MClientSession).
     op: "request_open" / "request_close" from the client,
     "open" / "close" from the MDS."""
@@ -38,7 +19,7 @@ class MClientSession(_JsonMessage):
 
 
 @register_message
-class MClientRequest(_JsonMessage):
+class MClientRequest(JsonMessage):
     """One metadata op.  `op` names the call (mkdir/create/lookup/
     readdir/unlink/rmdir/rename/setattr/getattr), `args` its operands
     (parent ino + dentry name addressing, like the reference's
@@ -48,6 +29,6 @@ class MClientRequest(_JsonMessage):
 
 
 @register_message
-class MClientReply(_JsonMessage):
+class MClientReply(JsonMessage):
     TYPE = 62
     FIELDS = ("tid", "rc", "outs", "result")

@@ -1,72 +1,52 @@
 """Monitor message types (reference ``src/messages/MMon*.h``,
 ``MOSDBoot/MOSDFailure/MOSDMap`` — SURVEY.md §3.2/§3.4).  Payloads are
-JSON-in-frame: the control plane optimizes for evolvability, not bytes.
+JSON-in-frame (``msg.message.JsonMessage``): the control plane
+optimizes for evolvability, not bytes.
 """
 
 from __future__ import annotations
 
-import json
-
-from ..msg.message import Message, register_message
-
-
-class _JsonMessage(Message):
-    """Base: one JSON object as payload."""
-
-    FIELDS: tuple = ()
-
-    def __init__(self, **kw):
-        super().__init__()
-        for f in self.FIELDS:
-            setattr(self, f, kw.get(f))
-
-    def encode_payload(self, enc):
-        enc.string(json.dumps({f: getattr(self, f) for f in self.FIELDS}))
-
-    def decode_payload(self, dec, version):
-        data = json.loads(dec.string())
-        for f in self.FIELDS:
-            setattr(self, f, data.get(f))
+from ..msg.message import JsonMessage, register_message
 
 
 @register_message
-class MMonElection(_JsonMessage):
+class MMonElection(JsonMessage):
     TYPE = 16
     FIELDS = ("payload",)
 
 
 @register_message
-class MMonPaxos(_JsonMessage):
+class MMonPaxos(JsonMessage):
     TYPE = 17
     FIELDS = ("payload",)
 
 
 @register_message
-class MMonCommand(_JsonMessage):
+class MMonCommand(JsonMessage):
     TYPE = 18
     FIELDS = ("tid", "cmd")       # cmd: dict with "prefix" etc.
 
 
 @register_message
-class MMonCommandReply(_JsonMessage):
+class MMonCommandReply(JsonMessage):
     TYPE = 19
     FIELDS = ("tid", "rc", "outs", "outb")  # status str, output obj
 
 
 @register_message
-class MMonSubscribe(_JsonMessage):
+class MMonSubscribe(JsonMessage):
     TYPE = 20
     FIELDS = ("what",)            # {"osdmap": start_epoch, ...}
 
 
 @register_message
-class MMonMap(_JsonMessage):
+class MMonMap(JsonMessage):
     TYPE = 21
     FIELDS = ("monmap",)
 
 
 @register_message
-class MOSDMapMsg(_JsonMessage):
+class MOSDMapMsg(JsonMessage):
     TYPE = 22
     # full map dict (epoch-stamped); `newest` is the mon's current
     # epoch so a subscriber replaying history (start>0 subscriptions
@@ -75,19 +55,19 @@ class MOSDMapMsg(_JsonMessage):
 
 
 @register_message
-class MOSDBoot(_JsonMessage):
+class MOSDBoot(JsonMessage):
     TYPE = 23
     FIELDS = ("osd", "addr", "fwd")
 
 
 @register_message
-class MOSDFailure(_JsonMessage):
+class MOSDFailure(JsonMessage):
     TYPE = 24
     FIELDS = ("target", "reporter", "fwd")
 
 
 @register_message
-class MOSDAlive(_JsonMessage):
+class MOSDAlive(JsonMessage):
     """A would-be primary asks the mon to record up_thru = want
     before it activates (reference ``src/messages/MOSDAlive.h``)."""
     TYPE = 25
@@ -95,7 +75,7 @@ class MOSDAlive(_JsonMessage):
 
 
 @register_message
-class MMDSBeacon(_JsonMessage):
+class MMDSBeacon(JsonMessage):
     """MDS → mon: liveness + desired state (reference
     ``src/messages/MMDSBeacon.h``).  addr is [host, port] of the MDS's
     client-facing messenger."""
@@ -104,14 +84,14 @@ class MMDSBeacon(_JsonMessage):
 
 
 @register_message
-class MFSMapMsg(_JsonMessage):
+class MFSMapMsg(JsonMessage):
     """Mon → subscriber: full FSMap push (reference MFSMap)."""
     TYPE = 28
     FIELDS = ("epoch", "fsmap")
 
 
 @register_message
-class MMgrBeacon(_JsonMessage):
+class MMgrBeacon(JsonMessage):
     """mgr → mon: liveness + address (reference
     ``src/messages/MMgrBeacon.h``)."""
     TYPE = 29
@@ -119,14 +99,14 @@ class MMgrBeacon(_JsonMessage):
 
 
 @register_message
-class MMgrMapMsg(_JsonMessage):
+class MMgrMapMsg(JsonMessage):
     """Mon → subscriber: full MgrMap push (reference MMgrMap)."""
     TYPE = 30
     FIELDS = ("epoch", "mgrmap")
 
 
 @register_message
-class MLog(_JsonMessage):
+class MLog(JsonMessage):
     """Daemon → mon: batched cluster-log entries (reference
     ``src/messages/MLog.h``).  entries: [{"stamp", "name", "channel",
     "prio", "text"}] — LogClient ships the unsent tail, LogMonitor
@@ -136,7 +116,7 @@ class MLog(_JsonMessage):
 
 
 @register_message
-class MMonEvent(_JsonMessage):
+class MMonEvent(JsonMessage):
     """Mon → "events" subscriber: one live event-stream record (the
     `ceph -w` feed — reference MLog/MMonHealth pushes folded into one
     frame).  kind: "health" | "clog" | "progress"; data: the record;
@@ -146,7 +126,7 @@ class MMonEvent(_JsonMessage):
 
 
 @register_message
-class MMonPing(_JsonMessage):
+class MMonPing(JsonMessage):
     """MonClient ↔ mon session keepalive (reference MonClient::tick
     keepalive + hunt).  Client sends ``tid``; the mon echoes it with
     ``ack=1`` and whether it currently sits in quorum — a silent or
@@ -158,7 +138,7 @@ class MMonPing(_JsonMessage):
 
 
 @register_message
-class MPGStats(_JsonMessage):
+class MPGStats(JsonMessage):
     """Primary OSD → mon: per-PG state/object counts (reference
     MPGStats → PGMap aggregation, ``src/mon/PGMap.cc``).  pg_stats:
     {pgid: {"state", "num_objects", "log_size", "last_scrub",

@@ -7,15 +7,16 @@ loops pure overhead), with the same externally visible contract:
 per-connection ordered delivery, typed messages, authenticated and
 CRC-protected frames, reconnect with session resume, fault injection.
 
-The DATA plane of this framework deliberately does NOT ride this
-messenger: bulk chunk movement between TPU shards is XLA collectives
-over ICI (``ceph_tpu.parallel``) — SURVEY.md §3.2's "TPU-native
-equivalent".  This messenger is the control plane (maps, peering,
-heartbeats, client ops).
+It carries the control plane (maps, peering, heartbeats) and the
+served data path: client ops, EC sub-writes and sub-reads, recovery
+pushes, their payload bytes as raw data segments after each message's
+JSON front (``message.JsonMessage``).  Chunk movement between the TPU
+shards of one process is XLA collectives over ICI
+(``ceph_tpu.parallel``).
 """
 
 from .fault import FaultInjector, FaultRule  # noqa: F401
-from .message import (MSG_REGISTRY, Message, MGenericPing,  # noqa: F401
-                      MGenericReply, register_message)
+from .message import (MSG_REGISTRY, JsonMessage, Message,  # noqa: F401
+                      MGenericPing, MGenericReply, register_message)
 from .messenger import (Connection, Dispatcher, EntityAddr,  # noqa: F401
                         Messenger)

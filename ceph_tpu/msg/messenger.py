@@ -57,6 +57,10 @@ TAG_ACK = 5
 TAG_KEEPALIVE = 6
 TAG_RESET = 7
 
+# frames below this size go to the transport as one buffer; larger
+# ones as their parts, so data segments are not copied into a frame
+_COALESCE_BYTES = 64 << 10
+
 
 @dataclass(frozen=True)
 class EntityAddr:
@@ -190,12 +194,12 @@ class Connection:
         self.msgr._conn_closed(self)
 
     async def _write_frame(self, tag: int, payload: bytes):
-        await self._put_frame(tag, payload).drain()
+        await self._put_frame(tag, [payload]).drain()
 
-    def _put_frame(self, tag: int, payload: bytes
-                   ) -> asyncio.StreamWriter:
-        """Build one frame and hand it to the transport (synchronous);
-        → the writer, for the caller to drain."""
+    def _put_frame(self, tag: int, parts: list) -> asyncio.StreamWriter:
+        """Build one frame around the payload ``parts`` (buffers whose
+        concatenation is the payload) and hand it to the transport
+        (synchronous); → the writer, for the caller to drain."""
         w = self._writer
         if w is None:
             raise ConnectionError("not connected")
@@ -210,17 +214,25 @@ class Connection:
             # AES-GCM with the frame tag as AAD: moving a ciphertext
             # under a different tag fails authentication, same as a
             # flipped payload bit
+            payload = parts[0] if len(parts) == 1 else b"".join(parts)
             wire = self.session_key.encrypt(payload, aad=bytes([tag]))
             crc = zlib.crc32(wire) & 0xFFFFFFFF
-            frame = struct.pack("<IBI", len(wire) + 5, tag, crc) + wire
+            w.write(struct.pack("<IBI", len(wire) + 5, tag, crc) + wire)
+            return w
+        crc = 0
+        for p in parts:
+            crc = zlib.crc32(p, crc)
+        sig = self.session_key.sign(*parts) if self.session_key else b""
+        size = sum(map(len, parts))
+        frame = [struct.pack("<IBI", size + 5 + len(sig), tag, crc),
+                 *parts, sig]
+        if size < _COALESCE_BYTES:
+            w.write(b"".join(frame))
         else:
-            crc = zlib.crc32(payload) & 0xFFFFFFFF
-            frame = struct.pack("<IBI", len(payload) + 5 +
-                                (8 if self.session_key else 0), tag, crc)
-            frame += payload
-            if self.session_key:
-                frame += self.session_key.sign(payload)
-        w.write(frame)
+            # large data segments go to the transport as they are: it
+            # keeps what the socket does not take at once as a view
+            for p in frame:
+                w.write(p)
         return w
 
     def _put_message(self, msg: Message) -> asyncio.StreamWriter:
@@ -228,12 +240,13 @@ class Connection:
         of a send, timed into the messenger's perf sink."""
         t0 = time.monotonic()
         with stage("msgr_send"):
-            payload = msg.encode()
-            w = self._put_frame(TAG_MSG, payload)
+            parts = msg.encode_parts()
+            w = self._put_frame(TAG_MSG, parts)
         perf = self.msgr.perf
         if perf is not None:
             perf.inc("msgr_send_messages")
-            perf.inc("msgr_send_bytes", len(payload))
+            perf.inc("msgr_send_bytes", sum(map(len, parts)))
+            perf.inc("msgr_send_data_bytes", msg.data_bytes)
             perf.tinc("msgr_running_send_time", time.monotonic() - t0)
         span = getattr(msg, "_wire_span", None)
         if span is not None:
@@ -270,7 +283,9 @@ class Connection:
                     from None
             return tag, payload
         siglen = 8 if self.session_key else 0
-        payload = body[:len(body) - siglen]
+        # a view, not a copy: data segments are copied out once, by
+        # the message decode
+        payload = memoryview(body)[:len(body) - siglen]
         if zlib.crc32(payload) & 0xFFFFFFFF != crc:
             raise ConnectionError("frame crc mismatch")
         if siglen:
@@ -307,6 +322,8 @@ class Connection:
         if perf is not None:
             perf.inc("msgr_recv_messages")
             perf.inc("msgr_recv_bytes", len(payload))
+            if msg is not None:
+                perf.inc("msgr_recv_data_bytes", msg.data_bytes)
             perf.tinc("msgr_running_recv_time", time.monotonic() - t0)
 
     async def _reader(self, r: asyncio.StreamReader, gen: int):

@@ -11,9 +11,11 @@ Reference behavior re-created (``src/os/ObjectStore.h``,
 - reads are synchronous (``read``, ``stat``, ``getattr``,
   ``omap_get``), exactly the reference's split.
 
-Transactions are dict-serializable so the replication backends can
-ship them inside ``MOSDRepOp`` / EC sub-write messages the way the
-reference encodes ``Transaction`` into those message payloads.
+A transaction has two serial forms, both decided here: ``to_wire`` for
+the ``MOSDRepOp`` / EC sub-write messages that ship it (the reference
+encodes ``Transaction`` into those payloads), whose byte arguments stay
+``bytes`` and ride as the message's raw data segments; and ``to_dict``,
+plain JSON with byte arguments hex-encoded, for the WAL record.
 """
 
 from __future__ import annotations
@@ -53,8 +55,8 @@ class Transaction:
     """An ordered opcode stream (reference ``ObjectStore::Transaction``).
 
     Ops are ``[opcode, cid, oid, *args]`` lists; byte payloads are kept
-    as ``bytes`` in memory and hex-encoded only by ``to_dict`` for the
-    wire.
+    as ``bytes`` in memory and on the wire, and hex-encoded only by
+    ``to_dict`` for the WAL record.
     """
 
     def __init__(self):
@@ -148,7 +150,18 @@ class Transaction:
         self.ops.extend(other.ops)
         return self
 
-    # -- wire form ---------------------------------------------------------
+    # -- wire form: the op lists as they are (a message carries each
+    # bytes argument, also inside an attr/omap map, as a raw segment) --
+    def to_wire(self) -> list:
+        return list(self.ops)
+
+    @classmethod
+    def from_wire(cls, data: list) -> "Transaction":
+        t = cls()
+        t.ops = list(data)
+        return t
+
+    # -- WAL record form ---------------------------------------------------
     # Tagged envelopes keep the decode unambiguous regardless of user
     # key names: a bytes arg becomes {"b": hex}, an attr/omap map
     # becomes {"d": {key: {"b": hex} | value}}.  (A user attr literally

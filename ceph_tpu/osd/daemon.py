@@ -132,6 +132,10 @@ def _build_osd_perf(name: str):
     b.add_u64_counter("msgr_send_bytes", "message frame payload bytes sent")
     b.add_u64_counter("msgr_recv_bytes",
                       "message frame payload bytes received")
+    b.add_u64_counter("msgr_send_data_bytes",
+                      "payload bytes sent as raw data segments")
+    b.add_u64_counter("msgr_recv_data_bytes",
+                      "payload bytes received as raw data segments")
     b.add_time_avg("msgr_running_send_time",
                    "message encode, frame build and socket write")
     b.add_time_avg("msgr_running_recv_time",
@@ -1667,9 +1671,8 @@ class OSDaemon(Dispatcher):
         self.perf.inc("op")
         self.perf.inc("op_w" if is_write else "op_r")
         if is_write:
-            # payload rides as hex text: 2 chars per byte
             in_bytes = sum(
-                len(op.get("data", "")) // 2 for op in (msg.ops or [])
+                len(op.get("data", b"")) for op in (msg.ops or [])
                 if op.get("op") in _WRITE_OPS)
             self.perf.inc("op_in_bytes", in_bytes)
             # stash for the reply-path attribution sketch (reads

@@ -3,26 +3,29 @@
 Reference: ``src/messages/MOSDOp.h``, ``MOSDOpReply.h``, ``MOSDRepOp.h``,
 ``MOSDRepOpReply.h``, ``MOSDPGQuery/Notify/Log.h``, ``MOSDECSubOpWrite/
 Read.h`` (ECMsgTypes), ``MOSDPing.h``, push/pull recovery messages
-(SURVEY.md §3.2/§3.5).  Like the mon plane, payloads are JSON-in-frame:
-the framed messenger carries them; bulk chunk bytes ride hex-encoded —
-the TPU data plane moves real bulk through JAX arrays/ICI, not through
-this control messenger, so wire-byte thrift here buys nothing.
+(SURVEY.md §3.2/§3.5).  Like the mon plane, each message is a JSON
+front; its byte values — op payloads, sub-write transactions' data,
+sub-read replies, recovery pushes, deep-scrub chunks — ride after it as
+raw data segments (``msg.message.JsonMessage``), as the reference's
+MOSDOp carries its data in the ``data`` bufferlist.  Every client byte
+crosses the wire once per hop, as itself.
 
 Field conventions:
 - ``reqid``: "client_name:tid" — the reference's osd_reqid_t, used for
   dup-op detection via the PG log.
 - ``version``: [epoch, v] pairs — eversion_t.
-- ``txn``: an ``os_store.Transaction.to_dict()`` opcode stream.
+- ``txn``: an ``os_store.Transaction.to_wire()`` opcode stream.
+- byte payloads (an op's "data", "kv" values, xattrs, chunks) are
+  ``bytes``.
 """
 
 from __future__ import annotations
 
-from ..mon.messages import _JsonMessage
-from ..msg.message import register_message
+from ..msg.message import JsonMessage, register_message
 
 
 @register_message
-class MOSDOp(_JsonMessage):
+class MOSDOp(JsonMessage):
     """Client → primary: one object op batch (reference MOSDOp).
     ``snapc``: the writer's SnapContext {"seq", "snaps"} from the pool
     (reference SnapContext riding every write); read ops may carry a
@@ -42,7 +45,7 @@ class MOSDOp(_JsonMessage):
 
 
 @register_message
-class MOSDOpReply(_JsonMessage):
+class MOSDOpReply(JsonMessage):
     """``dmc_phase``: which dmclock phase served the op —
     "reservation" or "priority" (reference PhaseType riding the
     reply) — the client's tracker feeds it back as rho.
@@ -54,7 +57,7 @@ class MOSDOpReply(_JsonMessage):
 
 
 @register_message
-class MOSDRepOp(_JsonMessage):
+class MOSDRepOp(JsonMessage):
     """Primary → replica: apply this transaction (ReplicatedBackend)."""
     TYPE = 42
     FIELDS = ("reqid", "pgid", "epoch", "txn", "version", "log_entries",
@@ -62,13 +65,13 @@ class MOSDRepOp(_JsonMessage):
 
 
 @register_message
-class MOSDRepOpReply(_JsonMessage):
+class MOSDRepOpReply(JsonMessage):
     TYPE = 43
     FIELDS = ("reqid", "pgid", "epoch", "rc", "from_osd")
 
 
 @register_message
-class MOSDPGQuery(_JsonMessage):
+class MOSDPGQuery(JsonMessage):
     """Primary → peer: send me your info/log (reference MOSDPGQuery;
     kind: "info" | "log"; since: eversion for log requests)."""
     TYPE = 44
@@ -76,7 +79,7 @@ class MOSDPGQuery(_JsonMessage):
 
 
 @register_message
-class MOSDPGNotify(_JsonMessage):
+class MOSDPGNotify(JsonMessage):
     """Peer → primary: my pg_info + my missing set (reference
     MOSDPGNotify; pg_missing_t travels with peering info)."""
     TYPE = 45
@@ -84,7 +87,7 @@ class MOSDPGNotify(_JsonMessage):
 
 
 @register_message
-class MOSDPGLog(_JsonMessage):
+class MOSDPGLog(JsonMessage):
     """Log share / activation (reference MOSDPGLog): when ``activate``
     is set the receiver adopts the authoritative info+log and goes
     active.  ``missing``: the sender's own missing set (peering)."""
@@ -94,7 +97,7 @@ class MOSDPGLog(_JsonMessage):
 
 
 @register_message
-class MOSDECSubOpWrite(_JsonMessage):
+class MOSDECSubOpWrite(JsonMessage):
     """Primary → shard k: write your chunk (reference MOSDECSubOpWrite)."""
     TYPE = 47
     FIELDS = ("reqid", "pgid", "shard", "epoch", "txn", "version",
@@ -102,27 +105,27 @@ class MOSDECSubOpWrite(_JsonMessage):
 
 
 @register_message
-class MOSDECSubOpWriteReply(_JsonMessage):
+class MOSDECSubOpWriteReply(JsonMessage):
     TYPE = 48
     FIELDS = ("reqid", "pgid", "shard", "epoch", "rc", "from_osd")
 
 
 @register_message
-class MOSDECSubOpRead(_JsonMessage):
+class MOSDECSubOpRead(JsonMessage):
     """Primary → shard: read chunk extents (reference MOSDECSubOpRead)."""
     TYPE = 49
     FIELDS = ("tid", "pgid", "shard", "epoch", "oid", "attrs")
 
 
 @register_message
-class MOSDECSubOpReadReply(_JsonMessage):
+class MOSDECSubOpReadReply(JsonMessage):
     TYPE = 50
     FIELDS = ("tid", "pgid", "shard", "epoch", "rc", "data", "attrs",
               "from_osd")
 
 
 @register_message
-class MOSDPing(_JsonMessage):
+class MOSDPing(JsonMessage):
     """OSD↔OSD heartbeat (reference MOSDPing; kind: "ping" |
     "ping_reply")."""
     TYPE = 51
@@ -130,28 +133,30 @@ class MOSDPing(_JsonMessage):
 
 
 @register_message
-class MOSDPGPush(_JsonMessage):
+class MOSDPGPush(JsonMessage):
     """Recovery push: full object (or shard chunk) state (reference
     MOSDPGPush carrying PushOp).  `clones`/`snapmap` carry the head's
     snap clones and their SnapMapper index rows — the reference's
     SnapSet-aware push (a recovered head without its clones would
-    silently lose snapshot history).  `dedup`: {fp: chunk frame hex}
+    silently lose snapshot history).  `dedup`: {fp: chunk frame}
     for a dedup-manifested head — chunk payloads travel with the
     manifest so the target can ingest them into its own refcount
-    index (decodes to None on pushes from older senders)."""
+    index (decodes to None on pushes from older senders).  ``data``,
+    ``attrs``/``omap`` values, clone payloads, snapmap rows and dedup
+    frames are bytes."""
     TYPE = 52
     FIELDS = ("pgid", "epoch", "oid", "data", "attrs", "omap", "version",
               "from_osd", "pull_tid", "clones", "snapmap", "dedup")
 
 
 @register_message
-class MOSDPGPushReply(_JsonMessage):
+class MOSDPGPushReply(JsonMessage):
     TYPE = 53
     FIELDS = ("pgid", "epoch", "oid", "from_osd")
 
 
 @register_message
-class MOSDPGPull(_JsonMessage):
+class MOSDPGPull(JsonMessage):
     """Primary-missing recovery: ask a peer holding the object to push
     it back (reference MOSDPGPull carrying PullOp)."""
     TYPE = 54
@@ -159,7 +164,7 @@ class MOSDPGPull(_JsonMessage):
 
 
 @register_message
-class MOSDScrubCommand(_JsonMessage):
+class MOSDScrubCommand(JsonMessage):
     """Mon → primary OSD: operator-requested scrub/repair of one PG
     (reference MOSDScrub, the `ceph pg scrub|deep-scrub|repair` path;
     our scrub repairs inconsistencies it finds, so repair implies
@@ -170,7 +175,7 @@ class MOSDScrubCommand(_JsonMessage):
 
 
 @register_message
-class MOSDRepScrub(_JsonMessage):
+class MOSDRepScrub(JsonMessage):
     """Primary → acting member: build and return your scrub map for
     this PG (reference MOSDRepScrub → replica ScrubMap build).
     ``deep``: read payloads and digest them (deep scrub); shallow
@@ -182,12 +187,12 @@ class MOSDRepScrub(_JsonMessage):
 
 
 @register_message
-class MOSDRepScrubMap(_JsonMessage):
+class MOSDRepScrubMap(JsonMessage):
     """Acting member → primary: my scrub map (reference
     MOSDRepScrubMap).  objects: {oid: {"size", "crc", "version",
     "valid"}} — for EC shards "crc" is the chunk CRC-32C and "valid"
     is the self-check against the stored hinfo; deep EC maps also
-    carry "data" (hex chunk payload) so the primary can re-run the
+    carry "data" (the chunk payload) so the primary can re-run the
     erasure code across shards (parity recheck)."""
     TYPE = 56
     FIELDS = ("pgid", "epoch", "scrub_tid", "shard", "objects",
@@ -195,7 +200,7 @@ class MOSDRepScrubMap(_JsonMessage):
 
 
 @register_message
-class MWatchNotify(_JsonMessage):
+class MWatchNotify(JsonMessage):
     """Primary → watching client: a notify fired on an object you
     watch (reference ``src/messages/MWatchNotify.h``)."""
     TYPE = 57
@@ -203,14 +208,14 @@ class MWatchNotify(_JsonMessage):
 
 
 @register_message
-class MWatchNotifyAck(_JsonMessage):
+class MWatchNotifyAck(JsonMessage):
     """Watching client → primary: notify delivered+handled."""
     TYPE = 58
     FIELDS = ("oid", "pgid", "notify_id", "watch_id", "reply")
 
 
 @register_message
-class MOSDBackoff(_JsonMessage):
+class MOSDBackoff(JsonMessage):
     """Primary → client: RADOS backoff (reference
     ``src/messages/MOSDBackoff.h``).  ``op`` is "block" or "unblock";
     a blocked client parks every op targeting (this OSD, this PG) and
@@ -223,7 +228,7 @@ class MOSDBackoff(_JsonMessage):
 
 
 @register_message
-class MOSDPGBackfillPrune(_JsonMessage):
+class MOSDPGBackfillPrune(JsonMessage):
     """Primary → backfill target: the authoritative object list; the
     target removes anything extraneous (reference backfill's
     remove-extraneous pass during the scan)."""

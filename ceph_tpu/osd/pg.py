@@ -1332,11 +1332,11 @@ class PG:
             ctx = ClsContext(read_xattr, exists, read_omap)
             try:
                 out = cls_call(op["cls"], op["method"], ctx,
-                               bytes.fromhex(op.get("data", "")))
+                               op.get("data", b""))
             except ClsError as e:
                 self._reply(msg, e.rc, str(e))
                 return None
-            call_results[len(new_ops)] = {"data": out.hex()}
+            call_results[len(new_ops)] = {"data": out}
             if ctx.staged_ops:
                 new_ops.extend(ctx.staged_ops)
             else:
@@ -1387,7 +1387,7 @@ class PG:
             try:
                 con.send_message(M.MWatchNotify(
                     oid=msg.oid, pgid=str(self.pgid), notify_id=nid,
-                    watch_id=wid, data=op.get("data", "")))
+                    watch_id=wid, data=op.get("data", b"")))
             except (ConnectionError, AttributeError):
                 st["waiting"].discard(wid)
         timeout = float(op.get("timeout", 10.0))
@@ -1634,7 +1634,7 @@ def _omap_read_result(kv: dict, op: dict) -> dict:
         kv = {k: kv[k] for k in want if k in kv}
     if op.get("keys_only"):
         return {"kv": {k: "" for k in kv}}
-    return {"kv": {k: v.hex() for k, v in kv.items()}}
+    return {"kv": kv}
 
 
 def _push_is_stale(store, cid: str, msg) -> bool:
@@ -1737,7 +1737,7 @@ class ReplicatedBackend(PGBackendBase):
         state = {"waiting": set(peers) | {_LOCAL_COMMIT}, "msg": msg,
                  "version": version, "results": results}
         self._inflight[reqid] = state
-        wire_txn = txn.to_dict()
+        wire_txn = txn.to_wire()
         # sub-ops join the trace as children of the OSD op span (fall
         # back to the client ctx when tracking was skipped)
         span = getattr(getattr(msg, "tracked", None), "span", None)
@@ -1836,10 +1836,10 @@ class ReplicatedBackend(PGBackendBase):
             if kind in _NOOP_OPS:
                 results.append({})
             elif kind == "write_full":
-                cur = bytes.fromhex(op["data"])
+                cur = op["data"]
                 results.append({})
             elif kind == "write":
-                buf = bytes.fromhex(op["data"])
+                buf = op["data"]
                 off = int(op.get("off", 0))
                 base = bytearray(cur)
                 if len(base) < off:
@@ -1848,7 +1848,7 @@ class ReplicatedBackend(PGBackendBase):
                 cur = bytes(base)
                 results.append({})
             elif kind == "append":
-                cur = cur + bytes.fromhex(op["data"])
+                cur = cur + op["data"]
                 results.append({})
             elif kind == "truncate":
                 size = int(op["size"])
@@ -1918,14 +1918,11 @@ class ReplicatedBackend(PGBackendBase):
             for op in attr_ops:
                 kind = op["op"]
                 if kind == "setxattr":
-                    txn.setattrs(cid, oid, {
-                        op["name"]: bytes.fromhex(op["data"])})
+                    txn.setattrs(cid, oid, {op["name"]: op["data"]})
                 elif kind == "rmxattr":
                     txn.rmattr(cid, oid, op["name"])
                 elif kind == "omap_set":
-                    txn.omap_setkeys(cid, oid, {
-                        k: bytes.fromhex(v)
-                        for k, v in op["kv"].items()})
+                    txn.omap_setkeys(cid, oid, op["kv"])
                 elif kind == "omap_rm":
                     txn.omap_rmkeys(cid, oid, list(op["keys"]))
             for fp, frame in ingest:
@@ -1944,7 +1941,7 @@ class ReplicatedBackend(PGBackendBase):
         state = {"waiting": set(peers) | {_LOCAL_COMMIT}, "msg": msg,
                  "version": version, "results": results}
         self._inflight[reqid] = state
-        wire_txn = txn.to_dict()
+        wire_txn = txn.to_wire()
         span = getattr(getattr(msg, "tracked", None), "span", None)
         trace = span.ctx() if span is not None \
             else getattr(msg, "trace", None)
@@ -2064,19 +2061,19 @@ class ReplicatedBackend(PGBackendBase):
             if kind in _NOOP_OPS:
                 results.append({})
             elif kind == "write":
-                data = bytes.fromhex(op["data"])
+                data = op["data"]
                 off = int(op.get("off", 0))
                 txn.write(cid, oid, off, data)
                 size = max(size, off + len(data))
                 results.append({})
             elif kind == "write_full":
-                data = bytes.fromhex(op["data"])
+                data = op["data"]
                 txn.truncate(cid, oid, 0)
                 txn.write(cid, oid, 0, data)
                 size = len(data)
                 results.append({})
             elif kind == "append":
-                data = bytes.fromhex(op["data"])
+                data = op["data"]
                 txn.write(cid, oid, size, data)
                 size += len(data)
                 results.append({})
@@ -2097,15 +2094,13 @@ class ReplicatedBackend(PGBackendBase):
                 delete = True
                 results.append({})
             elif kind == "setxattr":
-                txn.setattrs(cid, oid,
-                             {op["name"]: bytes.fromhex(op["data"])})
+                txn.setattrs(cid, oid, {op["name"]: op["data"]})
                 results.append({})
             elif kind == "rmxattr":
                 txn.rmattr(cid, oid, op["name"])
                 results.append({})
             elif kind == "omap_set":
-                txn.omap_setkeys(cid, oid, {
-                    k: bytes.fromhex(v) for k, v in op["kv"].items()})
+                txn.omap_setkeys(cid, oid, op["kv"])
                 results.append({})
             elif kind == "omap_rm":
                 txn.omap_rmkeys(cid, oid, list(op["keys"]))
@@ -2140,7 +2135,7 @@ class ReplicatedBackend(PGBackendBase):
     def apply_rep_op(self, msg: M.MOSDRepOp):
         pg, daemon = self.pg, self.pg.daemon
         daemon.perf.inc("subop")
-        txn = Transaction.from_dict(msg.txn)
+        txn = Transaction.from_wire(msg.txn)
         for ed in msg.log_entries or []:
             e = LogEntry.from_dict(ed)
             # this txn supersedes pending recovery for the object even
@@ -2187,13 +2182,13 @@ class ReplicatedBackend(PGBackendBase):
                     length = op.get("len")
                     end = (len(full) if length is None
                            else off + int(length))
-                    results.append({"data": full[off:end].hex()})
+                    results.append({"data": full[off:end]})
                 else:
                     length = op.get("len")
                     data = store.read(
                         cid, src, int(op.get("off", 0)),
                         None if length is None else int(length))
-                    results.append({"data": data.hex()})
+                    results.append({"data": data})
             elif kind == "stat":
                 meta = self._read_local_meta(src)
                 size = (int(meta["size"]) if meta and "size" in meta
@@ -2202,10 +2197,10 @@ class ReplicatedBackend(PGBackendBase):
                                 "version": self._object_version(oid)})
             elif kind == "getxattr":
                 results.append(
-                    {"data": store.getattr(cid, oid, op["name"]).hex()})
+                    {"data": store.getattr(cid, oid, op["name"])})
             elif kind == "getxattrs":
                 results.append({"attrs": {
-                    k: v.hex() for k, v in store.getattrs(cid, oid).items()
+                    k: v for k, v in store.getattrs(cid, oid).items()
                     if k != "_"}})
             elif kind == "omap_get":
                 results.append(_omap_read_result(
@@ -2384,10 +2379,8 @@ class ReplicatedBackend(PGBackendBase):
             return None, None
         for o in siblings:
             if o.startswith(prefix):
-                clones[o] = {
-                    "data": store.read(cid, o).hex(),
-                    "attrs": {k: v.hex() for k, v in
-                              store.getattrs(cid, o).items()}}
+                clones[o] = {"data": store.read(cid, o),
+                             "attrs": store.getattrs(cid, o)}
         rows = {}
         try:
             snapmap = store.omap_get(cid, SNAPMAP_OID)
@@ -2395,12 +2388,12 @@ class ReplicatedBackend(PGBackendBase):
             snapmap = {}
         for key, val in snapmap.items():
             if key.split("|", 1)[1].rsplit("|", 1)[0] == oid:
-                rows[key] = val.hex()
+                rows[key] = val
         return clones or None, rows or None
 
     @staticmethod
     def _dedup_payload(store, attrs) -> dict | None:
-        """{fp: chunk frame hex} for a manifested head's push — chunk
+        """{fp: chunk frame} for a manifested head's push — chunk
         payloads travel with the manifest so the target can ingest
         them into its own refcount index."""
         from ..compress import dedup as dd
@@ -2414,7 +2407,7 @@ class ReplicatedBackend(PGBackendBase):
                 continue
             try:
                 frames[fp] = bytes(store.read(
-                    dd.DEDUP_COLL, dd.chunk_oid(fp))).hex()
+                    dd.DEDUP_COLL, dd.chunk_oid(fp)))
             except KeyError:
                 continue
         return frames or None
@@ -2431,9 +2424,7 @@ class ReplicatedBackend(PGBackendBase):
         clones, snaprows = self._snap_payload(daemon.store, cid, oid)
         daemon.send_to_osd(peer, M.MOSDPGPush(
             pgid=str(pg.pgid), epoch=daemon.osdmap.epoch, oid=oid,
-            data=data.hex(),
-            attrs={k: v.hex() for k, v in attrs.items()},
-            omap={k: v.hex() for k, v in omap.items()},
+            data=data, attrs=attrs, omap=omap,
             version=list(version), from_osd=daemon.whoami,
             pull_tid=None, clones=clones, snapmap=snaprows,
             dedup=self._dedup_payload(daemon.store, attrs)))
@@ -2459,9 +2450,7 @@ class ReplicatedBackend(PGBackendBase):
                                               msg.oid)
         daemon.send_to_osd(msg.from_osd, M.MOSDPGPush(
             pgid=str(pg.pgid), epoch=daemon.osdmap.epoch, oid=msg.oid,
-            data=data.hex(),
-            attrs={k: v.hex() for k, v in attrs.items()},
-            omap={k: v.hex() for k, v in omap.items()},
+            data=data, attrs=attrs, omap=omap,
             version=meta.get("version", list(ZERO)),
             from_osd=daemon.whoami, pull_tid=msg.pull_tid,
             clones=clones, snapmap=snaprows,
@@ -2490,39 +2479,32 @@ class ReplicatedBackend(PGBackendBase):
         t.remove(cid, msg.oid)
         t.touch(cid, msg.oid)
         if msg.data:
-            t.write(cid, msg.oid, 0, bytes.fromhex(msg.data))
+            t.write(cid, msg.oid, 0, msg.data)
         if msg.attrs:
-            t.setattrs(cid, msg.oid,
-                       {k: bytes.fromhex(v) for k, v in msg.attrs.items()})
+            t.setattrs(cid, msg.oid, msg.attrs)
         # dedup bookkeeping: ingest the pushed manifest's chunks (one
         # ref per entry) BEFORE releasing the replaced local copy's
         # references — shared chunks must never dip to zero
         new_meta = None
         try:
-            new_meta = json.loads(bytes.fromhex(
-                (msg.attrs or {}).get("_", "")))
+            new_meta = json.loads((msg.attrs or {}).get("_", b""))
         except ValueError:
             pass
         frames = msg.dedup or {}
         for fp, _ln in dd.manifest_entries(new_meta):
             if fp in frames:
-                t.dedup_ingest(dd.DEDUP_COLL, fp,
-                               bytes.fromhex(frames[fp]))
+                t.dedup_ingest(dd.DEDUP_COLL, fp, frames[fp])
         for fp, _ln in dd.manifest_entries(old_meta):
             t.dedup_release(dd.DEDUP_COLL, fp)
         if msg.omap:
-            t.omap_setkeys(cid, msg.oid, {
-                k: bytes.fromhex(v) for k, v in msg.omap.items()})
+            t.omap_setkeys(cid, msg.oid, msg.omap)
         for coid, payload in (msg.clones or {}).items():
             t.remove(cid, coid)
-            t.write(cid, coid, 0, bytes.fromhex(payload["data"]))
+            t.write(cid, coid, 0, payload["data"])
             if payload.get("attrs"):
-                t.setattrs(cid, coid, {
-                    k: bytes.fromhex(v)
-                    for k, v in payload["attrs"].items()})
+                t.setattrs(cid, coid, payload["attrs"])
         if msg.snapmap:
-            t.omap_setkeys(cid, SNAPMAP_OID, {
-                k: bytes.fromhex(v) for k, v in msg.snapmap.items()})
+            t.omap_setkeys(cid, SNAPMAP_OID, msg.snapmap)
         pg.missing.pop(msg.oid, None)
         pg._persist_meta(t)
         daemon.store.queue_transaction(t)
@@ -2698,11 +2680,11 @@ class ECBackend(PGBackendBase):
             if kind in _NOOP_OPS:
                 results.append({})
             elif kind == "write_full":
-                cur = bytes.fromhex(op["data"])
+                cur = op["data"]
                 data = cur
                 results.append({})
             elif kind == "write":
-                buf = bytes.fromhex(op["data"])
+                buf = op["data"]
                 off = int(op.get("off", 0))
                 base = bytearray(cur or b"")
                 if len(base) < off:
@@ -2712,7 +2694,7 @@ class ECBackend(PGBackendBase):
                 data = cur
                 results.append({})
             elif kind == "append":
-                cur = (cur or b"") + bytes.fromhex(op["data"])
+                cur = (cur or b"") + op["data"]
                 data = cur
                 results.append({})
             elif kind == "truncate":
@@ -2879,7 +2861,7 @@ class ECBackend(PGBackendBase):
                                   hinfos=hinfos, extra=extra)
             daemon.send_to_osd(o, M.MOSDECSubOpWrite(
                 reqid=reqid, pgid=str(pg.pgid), shard=s,
-                epoch=daemon.osdmap.epoch, txn=txn.to_dict(),
+                epoch=daemon.osdmap.epoch, txn=txn.to_wire(),
                 version=list(version),
                 log_entries=[entry.to_dict()],
                 pg_info=pg.info.to_dict(), trace=trace))
@@ -2914,13 +2896,11 @@ class ECBackend(PGBackendBase):
         for op in attr_ops:
             kind = op["op"]
             if kind == "setxattr":
-                t.setattrs(cid, oid,
-                           {op["name"]: bytes.fromhex(op["data"])})
+                t.setattrs(cid, oid, {op["name"]: op["data"]})
             elif kind == "rmxattr":
                 t.rmattr(cid, oid, op["name"])
             elif kind == "omap_set":
-                t.omap_setkeys(cid, oid, {
-                    k: bytes.fromhex(v) for k, v in op["kv"].items()})
+                t.omap_setkeys(cid, oid, op["kv"])
             elif kind == "omap_rm":
                 t.omap_rmkeys(cid, oid, list(op["keys"]))
         return t
@@ -2944,7 +2924,7 @@ class ECBackend(PGBackendBase):
         with stage("osd_subop"):
             pg, daemon = self.pg, self.pg.daemon
             daemon.perf.inc("subop")
-            txn = Transaction.from_dict(msg.txn)
+            txn = Transaction.from_wire(msg.txn)
             entries = [LogEntry.from_dict(e)
                        for e in msg.log_entries or []]
             reply = M.MOSDECSubOpWriteReply(
@@ -3048,10 +3028,10 @@ class ECBackend(PGBackendBase):
                                "version": tuple(meta["version"])})
             elif kind == "getxattr":
                 simple.append({"data": self.pg.daemon.store.getattr(
-                    pg.cid, oid, op["name"]).hex()})
+                    pg.cid, oid, op["name"])})
             elif kind == "getxattrs":
                 simple.append({"attrs": {
-                    k: v.hex() for k, v in
+                    k: v for k, v in
                     self.pg.daemon.store.getattrs(pg.cid, oid).items()
                     if k != "_"}})
             elif kind == "omap_get":
@@ -3219,8 +3199,8 @@ class ECBackend(PGBackendBase):
             data, meta, rc = b"", b"{}", -2
         daemon.send_to_osd(pg.primary, M.MOSDECSubOpReadReply(
             tid=msg.tid, pgid=msg.pgid, shard=msg.shard,
-            epoch=daemon.osdmap.epoch, rc=rc, data=data.hex(),
-            attrs={"_": meta.hex()}, from_osd=daemon.whoami))
+            epoch=daemon.osdmap.epoch, rc=rc, data=data,
+            attrs={"_": meta}, from_osd=daemon.whoami))
 
     _MIXED_RETRIES = 8
     _MIXED_RETRY_DELAY = 0.25
@@ -3261,10 +3241,10 @@ class ECBackend(PGBackendBase):
             if st["msg"] is not None:
                 self.pg._reply(st["msg"], msg.rc, "shard read failed")
             return
-        chunk = bytes.fromhex(msg.data)
+        chunk = msg.data
         # verify the per-chunk checksum before trusting it (reference
         # HashInfo crc verification on sub-read)
-        meta = json.loads(bytes.fromhex(msg.attrs["_"]))
+        meta = json.loads(msg.attrs["_"])
         hinfo = meta.get("hinfo")
         if hinfo is not None and crc32c(chunk) != hinfo:
             del self._reads[msg.tid]
@@ -3492,7 +3472,7 @@ class ECBackend(PGBackendBase):
                 off = int(op.get("off", 0))
                 ln = op.get("len")
                 end = len(payload) if ln is None else off + int(ln)
-                results.append({"data": payload[off:end].hex()})
+                results.append({"data": payload[off:end]})
             elif kind == "stat":
                 results.append({"size": size,
                                 "version": tuple(meta["version"])})
@@ -3528,12 +3508,12 @@ class ECBackend(PGBackendBase):
             chunk = decoded[shard].tobytes()
             pg.daemon.send_to_osd(peer, M.MOSDPGPush(
                 pgid=str(pg.pgid), epoch=pg.daemon.osdmap.epoch,
-                oid=oid, data=chunk.hex(),
+                oid=oid, data=chunk,
                 attrs={"_": _obj_meta(
                     tuple(meta.get("version", version)),
                     int(meta.get("size", 0)),
                     hinfo=crc32c(chunk),
-                    extra=_meta_extra(meta)).hex()},
+                    extra=_meta_extra(meta))},
                 omap={}, version=list(version),
                 from_osd=pg.daemon.whoami, pull_tid=None))
 
@@ -3580,8 +3560,8 @@ class ECBackend(PGBackendBase):
         """oid → {size, crc, version, valid}: each EC shard verifies
         its own chunk against the stored hinfo crc (reference deep
         scrub on EC shards).  Deep maps digest chunks through the
-        batched CRC-32C kernel and carry the chunk payload ("data",
-        hex) so the primary can re-run the erasure code across shards
+        batched CRC-32C kernel and carry the chunk payload ("data")
+        so the primary can re-run the erasure code across shards
         — the parity recheck that catches bit-rot whose hinfo was
         rewritten consistently.  Shallow maps are presence/size only
         (no data read, no self-check)."""
@@ -3615,7 +3595,7 @@ class ECBackend(PGBackendBase):
             for oid, digest in digests.items():
                 hinfo = metas[oid].get("hinfo")
                 out[oid].update(
-                    crc=digest, data=chunks[oid].hex(),
+                    crc=digest, data=chunks[oid],
                     valid=hinfo is None or digest == hinfo)
             if span is not None:
                 span.finish()
@@ -3699,7 +3679,7 @@ class ECBackend(PGBackendBase):
                 e = m.get(oid)
                 if e is None or "data" not in e or osd not in shard_of:
                     continue
-                chunks[shard_of[osd]] = bytes.fromhex(e["data"])
+                chunks[shard_of[osd]] = e["data"]
             if (len(chunks) == n
                     and len({len(c) for c in chunks.values()}) == 1):
                 stripes[oid] = chunks
@@ -3766,10 +3746,9 @@ class ECBackend(PGBackendBase):
         if not pg.daemon.store.collection_exists(cid):
             t.create_collection(cid)
         t.remove(cid, msg.oid)
-        t.write(cid, msg.oid, 0, bytes.fromhex(msg.data))
+        t.write(cid, msg.oid, 0, msg.data)
         if msg.attrs:
-            t.setattrs(cid, msg.oid,
-                       {k: bytes.fromhex(v) for k, v in msg.attrs.items()})
+            t.setattrs(cid, msg.oid, msg.attrs)
         pg.missing.pop(msg.oid, None)
         pg._note_local_object_write()
         pg._persist_meta(t)

@@ -5,6 +5,12 @@ SURVEY.md §3.8): a cluster handle (`Rados`) opens per-pool I/O contexts
 (`IoCtx`); object ops compose into one submission (the reference's
 ``ObjectWriteOperation``); sync wrappers ride the async engine, and
 ``aio_*`` return `Completion` objects with ``wait_for_complete``.
+
+Methods that take or return ``bytes`` hand them to the objecter as they
+are: they ride the wire as raw data segments, never as hex.  The
+op-dict form (``IoCtx._aio`` / ``_sync`` ops, and the results of
+``aio_read`` and ``_aio``/``_sync`` completions) keeps hex strings for
+its callers; the objecter converts it at the edge.
 """
 
 from __future__ import annotations
@@ -193,11 +199,11 @@ class Rados:
                     # ONE compound op: the version and the bytes come
                     # from the same serialized execution
                     res, _ = cache_io._sync(oid, [
-                        {"op": "stat"}, {"op": "read"}])
+                        {"op": "stat"}, {"op": "read"}], raw=True)
                 except ObjectNotFound:
                     continue    # raced a delete
                 ver = res[0].get("version")
-                data = bytes.fromhex(res[1].get("data", ""))
+                data = res[1].get("data", b"")
                 base_io.write_full(oid, data)
                 try:
                     for k, v in cache_io.getxattrs(oid).items():
@@ -240,28 +246,31 @@ class IoCtx:
         self.direct = direct
 
     # -- async engine ------------------------------------------------------
-    def _aio(self, oid: str, ops: list[dict]) -> Completion:
+    def _aio(self, oid: str, ops: list[dict], raw: bool = False
+             ) -> Completion:
+        """Submit op-dicts; results hex unless ``raw`` (bytes)."""
         c = Completion()
         self.objecter.op_submit(self.pool_id, oid, ops, c._complete,
-                                direct=self.direct)
+                                direct=self.direct, raw=raw)
         return c
 
-    def _sync(self, oid: str, ops: list[dict], timeout: float = 10.0):
+    def _sync(self, oid: str, ops: list[dict], timeout: float = 10.0,
+              raw: bool = False):
         rc, outs, results, version = self.objecter.operate(
-            self.pool_id, oid, ops, timeout, direct=self.direct)
+            self.pool_id, oid, ops, timeout, direct=self.direct, raw=raw)
         _raise(rc, outs)
         return results, version
 
     # -- writes ------------------------------------------------------------
     def write_full(self, oid: str, data: bytes):
-        self._sync(oid, [{"op": "write_full", "data": data.hex()}])
+        self._sync(oid, [{"op": "write_full", "data": data}], raw=True)
 
     def write(self, oid: str, data: bytes, off: int = 0):
-        self._sync(oid, [{"op": "write", "off": off,
-                          "data": data.hex()}])
+        self._sync(oid, [{"op": "write", "off": off, "data": data}],
+                   raw=True)
 
     def append(self, oid: str, data: bytes):
-        self._sync(oid, [{"op": "append", "data": data.hex()}])
+        self._sync(oid, [{"op": "append", "data": data}], raw=True)
 
     def truncate(self, oid: str, size: int):
         self._sync(oid, [{"op": "truncate", "size": size}])
@@ -271,14 +280,13 @@ class IoCtx:
 
     def setxattr(self, oid: str, name: str, value: bytes):
         self._sync(oid, [{"op": "setxattr", "name": name,
-                          "data": value.hex()}])
+                          "data": value}], raw=True)
 
     def rmxattr(self, oid: str, name: str):
         self._sync(oid, [{"op": "rmxattr", "name": name}])
 
     def omap_set(self, oid: str, kv: dict[str, bytes]):
-        self._sync(oid, [{"op": "omap_set",
-                          "kv": {k: v.hex() for k, v in kv.items()}}])
+        self._sync(oid, [{"op": "omap_set", "kv": dict(kv)}], raw=True)
 
     def omap_rm_keys(self, oid: str, keys: list[str]):
         self._sync(oid, [{"op": "omap_rm", "keys": list(keys)}])
@@ -289,8 +297,8 @@ class IoCtx:
         rados_exec / IoCtx::exec)."""
         results, _ = self._sync(oid, [{"op": "call", "cls": cls,
                                        "method": method,
-                                       "data": data.hex()}])
-        return bytes.fromhex(results[0].get("data", ""))
+                                       "data": data}], raw=True)
+        return results[0].get("data", b"")
 
     def lock_exclusive(self, oid: str, name: str, cookie: str,
                        entity: str = ""):
@@ -354,8 +362,8 @@ class IoCtx:
         op = {"op": "read", "off": off, "snapid": sid}
         if length is not None:
             op["len"] = length
-        results, _ = self._sync(oid, [op])
-        return bytes.fromhex(results[0]["data"])
+        results, _ = self._sync(oid, [op], raw=True)
+        return results[0]["data"]
 
     # -- watch/notify ------------------------------------------------------
     def watch(self, oid: str, callback) -> str:
@@ -384,16 +392,17 @@ class IoCtx:
         timeout lapses.  Returns {"replies": {watch_id: reply},
         "timed_out_watchers": [...]} (reference rados_notify2)."""
         results, _ = self._sync(oid, [{"op": "notify",
-                                       "data": payload.hex(),
+                                       "data": payload,
                                        "timeout": timeout}],
-                                timeout=timeout + 10.0)
+                                timeout=timeout + 10.0, raw=True)
         return results[0]
 
     def aio_write_full(self, oid: str, data: bytes) -> Completion:
-        return self._aio(oid, [{"op": "write_full", "data": data.hex()}])
+        return self._aio(oid, [{"op": "write_full", "data": data}],
+                         raw=True)
 
     def aio_append(self, oid: str, data: bytes) -> Completion:
-        return self._aio(oid, [{"op": "append", "data": data.hex()}])
+        return self._aio(oid, [{"op": "append", "data": data}], raw=True)
 
     def aio_remove(self, oid: str) -> Completion:
         return self._aio(oid, [{"op": "delete"}])
@@ -404,11 +413,13 @@ class IoCtx:
         op = {"op": "read", "off": off}
         if length is not None:
             op["len"] = length
-        results, _ = self._sync(oid, [op])
-        return bytes.fromhex(results[0]["data"])
+        results, _ = self._sync(oid, [op], raw=True)
+        return results[0]["data"]
 
     def aio_read(self, oid: str, length: int | None = None,
                  off: int = 0) -> Completion:
+        """Its completion's ``results`` are in the op-dict form: the
+        read's "data" is hex."""
         op = {"op": "read", "off": off}
         if length is not None:
             op["len"] = length
@@ -419,13 +430,13 @@ class IoCtx:
         return results[0]
 
     def getxattr(self, oid: str, name: str) -> bytes:
-        results, _ = self._sync(oid, [{"op": "getxattr", "name": name}])
-        return bytes.fromhex(results[0]["data"])
+        results, _ = self._sync(oid, [{"op": "getxattr", "name": name}],
+                                raw=True)
+        return results[0]["data"]
 
     def getxattrs(self, oid: str) -> dict[str, bytes]:
-        results, _ = self._sync(oid, [{"op": "getxattrs"}])
-        return {k: bytes.fromhex(v)
-                for k, v in results[0]["attrs"].items()}
+        results, _ = self._sync(oid, [{"op": "getxattrs"}], raw=True)
+        return results[0]["attrs"]
 
     def omap_get(self, oid: str, keys: list[str] | None = None
                  ) -> dict[str, bytes]:
@@ -434,8 +445,8 @@ class IoCtx:
         op = {"op": "omap_get"}
         if keys is not None:
             op["keys"] = list(keys)
-        results, _ = self._sync(oid, [op])
-        return {k: bytes.fromhex(v) for k, v in results[0]["kv"].items()}
+        results, _ = self._sync(oid, [op], raw=True)
+        return results[0]["kv"]
 
     def omap_get_keys(self, oid: str) -> list[str]:
         """Key names only (reference omap_get_keys): no values cross

@@ -13,6 +13,12 @@ Reference behavior re-created (``src/osdc/Objecter.{h,cc}``; SURVEY.md
   mid-workload loses nothing — duplicate delivery is absorbed by the
   PG-log reqid dup detection on the OSD;
 - connection resets requeue every op targeted at that OSD.
+
+Ops carry their payloads as ``bytes`` (raw data segments on the wire).
+The op-dict API (``op_submit``/``operate``, librados ``IoCtx._aio`` /
+``_sync``) also takes hex strings and returns hex results, unless the
+caller asks for ``raw`` replies; ``_wire_ops`` and ``_hex_results`` are
+the one place that converts.
 """
 
 from __future__ import annotations
@@ -29,13 +35,50 @@ from ..osd.osdmap import CrushMemo, OSDMap, PGid
 from ..tools.osdmaptool import osdmap_from_dict
 
 
+def _wire_ops(ops: list[dict]) -> list[dict]:
+    """Ops as MOSDOp carries them: a hex payload (an op's "data", the
+    values of its "kv") becomes bytes; bytes pass through."""
+    out = []
+    for op in ops:
+        data, kv = op.get("data"), op.get("kv")
+        hex_kv = bool(kv) and any(isinstance(v, str) for v in kv.values())
+        if isinstance(data, str) or hex_kv:
+            op = dict(op)
+            if isinstance(data, str):
+                op["data"] = bytes.fromhex(data)
+            if hex_kv:
+                op["kv"] = {k: bytes.fromhex(v) if isinstance(v, str)
+                            else v for k, v in kv.items()}
+        out.append(op)
+    return out
+
+
+def _hex(v):
+    if isinstance(v, bytes):
+        return v.hex()
+    if isinstance(v, dict):
+        return {k: x.hex() if isinstance(x, bytes) else x
+                for k, x in v.items()}
+    return v
+
+
+def _hex_results(results):
+    """Reply results in the op-dict form: bytes values (a read's
+    "data", getxattrs' "attrs", omap_get's "kv") as hex."""
+    if not results:
+        return results
+    return [{k: _hex(v) for k, v in r.items()} if isinstance(r, dict)
+            else r for r in results]
+
+
 class _Op:
     __slots__ = ("tid", "pool", "oid", "ops", "on_reply", "pgid",
                  "target_osd", "attempts", "submitted", "direct",
-                 "next_resend", "resend_delay", "span", "qos_client")
+                 "next_resend", "resend_delay", "span", "qos_client",
+                 "raw")
 
     def __init__(self, tid, pool, oid, ops, on_reply, direct=False,
-                 qos_client=None):
+                 qos_client=None, raw=False):
         self.tid = tid
         self.pool = pool
         self.oid = oid
@@ -51,6 +94,7 @@ class _Op:
         self.resend_delay = 0.0
         self.span = None            # objecter op span when tracing
         self.qos_client = qos_client    # tenant tag for mClock
+        self.raw = raw              # reply results as bytes, not hex
 
 
 class BackoffRegistry:
@@ -301,11 +345,15 @@ class Objecter(Dispatcher):
         return getattr(self._qos_local, "tag", None)
 
     def op_submit(self, pool: int, oid: str, ops: list[dict],
-                  on_reply, direct: bool = False) -> int:
+                  on_reply, direct: bool = False, raw: bool = False) -> int:
+        """Submit ``ops`` (payloads as bytes or hex); ``on_reply`` gets
+        results whose byte values are hex, or bytes with ``raw``."""
+        ops = _wire_ops(ops)
         with self.lock:
             self._tid += 1
-            op = _Op(self._tid, pool, oid, list(ops), on_reply,
-                     direct=direct, qos_client=self.get_qos_tag())
+            op = _Op(self._tid, pool, oid, ops, on_reply,
+                     direct=direct, qos_client=self.get_qos_tag(),
+                     raw=raw)
             op.span = self.tracer.start_span(
                 f"objecter_op:{oid}",
                 tags={"layer": "objecter", "pool": pool,
@@ -430,8 +478,7 @@ class Objecter(Dispatcher):
             reply = None
             if cb is not None:
                 try:
-                    reply = cb(msg.notify_id, msg.oid,
-                               bytes.fromhex(msg.data or ""))
+                    reply = cb(msg.notify_id, msg.oid, msg.data or b"")
                 except Exception:
                     reply = None
             try:
@@ -481,7 +528,8 @@ class Objecter(Dispatcher):
             op.span.set_tag("rc", msg.rc)
             op.span.set_tag("attempts", op.attempts)
             op.span.finish()
-        op.on_reply(msg.rc, msg.outs, msg.results,
+        op.on_reply(msg.rc, msg.outs,
+                    msg.results if op.raw else _hex_results(msg.results),
                     tuple(msg.version or (0, 0)))
         return True
 
@@ -508,7 +556,8 @@ class Objecter(Dispatcher):
 
     # -- sync convenience --------------------------------------------------
     def operate(self, pool: int, oid: str, ops: list[dict],
-                timeout: float = 10.0, direct: bool = False):
+                timeout: float = 10.0, direct: bool = False,
+                raw: bool = False):
         """→ (rc, outs, results, version) with resend-until-timeout."""
         ev = threading.Event()
         box: list = []
@@ -517,7 +566,8 @@ class Objecter(Dispatcher):
             box.append((rc, outs, results, version))
             ev.set()
 
-        tid = self.op_submit(pool, oid, ops, on_reply, direct=direct)
+        tid = self.op_submit(pool, oid, ops, on_reply, direct=direct,
+                             raw=raw)
         if not ev.wait(timeout):
             with self.lock:
                 op = self.inflight.pop(tid, None)
