@@ -17,11 +17,17 @@ ENCODE_START/FINISH pair as a context manager.  ``Encoder.blob_ref``
 appends a blob without copying it in: ``parts()`` hands the encoding
 back as a list of buffers with each such blob its own element (the
 reference's bufferlist of data segments after a message front).
+
+``encode_json_segments`` / ``decode_json_segments`` decide the one form
+of a JSON value whose byte values travel as such data segments: the
+messages of ``msg/message.py`` and the object store's WAL record
+(``os_store/objectstore.py``) are both written in it.
 """
 
 from __future__ import annotations
 
 import contextlib
+import json
 import struct
 
 from .buffer import BufferList
@@ -242,3 +248,77 @@ class _Block:
 
     def has_more(self) -> bool:
         return self.dec._pos < self._end
+
+
+# -- a JSON front, then its byte values as raw data segments ---------------
+# A segment reference in the front is {_SEG: index}.  In a value that
+# carries segments, every user dict key starting with NUL gains one more
+# NUL, so no user dict can read as a reference.
+_NUL = "\x00"
+_SEG = _NUL + "seg"
+_BULK = (bytes, bytearray, memoryview)
+_JSON_NUL = "\\u0000"      # how json.dumps writes a NUL
+
+
+def _escape_keys(obj):
+    if isinstance(obj, dict):
+        return {(_NUL + k if isinstance(k, str) and k.startswith(_NUL)
+                 else k): _escape_keys(v) for k, v in obj.items()}
+    if isinstance(obj, (list, tuple)):
+        return [_escape_keys(v) for v in obj]
+    return obj
+
+
+def _restorer(segs: list, escaped: bool):
+    """json object_hook: references → their segments; unescape keys."""
+    def restore(d):
+        if _SEG in d and len(d) == 1:
+            i = d[_SEG]
+            if type(i) is not int or not 0 <= i < len(segs):
+                raise DecodeError(f"bad data segment reference {i!r}")
+            return segs[i]
+        if escaped:
+            return {(k[1:] if k.startswith(_NUL) else k): v
+                    for k, v in d.items()}
+        return d
+    return restore
+
+
+def encode_json_segments(enc: Encoder, obj) -> list:
+    """Write ``obj`` as its JSON front (a string), then a u32 count and
+    each of its byte values (``bytes``, ``bytearray``, ``memoryview``,
+    at any depth) as a ``blob_ref`` segment; → the segments.  A value
+    with no byte values writes its plain JSON and zero segments."""
+    segs = []
+
+    def ref(v):
+        if isinstance(v, _BULK):
+            segs.append(v)
+            return {_SEG: len(segs) - 1}
+        raise TypeError(f"{type(v).__name__} is not JSON serializable")
+
+    front = json.dumps(obj, default=ref)
+    if segs and front.count(_JSON_NUL) != len(segs):
+        # a NUL besides the references: a user key may start with
+        # one, so escape the keys (the decoder sees the same count)
+        segs.clear()
+        front = json.dumps(_escape_keys(obj), default=ref)
+    enc.string(front)
+    enc.u32(len(segs))
+    for seg in segs:
+        enc.blob_ref(seg)
+    return segs
+
+
+def decode_json_segments(dec: Decoder, segments: bool = True):
+    """The inverse of ``encode_json_segments`` → ``(obj, segments)``;
+    ``segments=False`` reads a bare front (a message of the vintage
+    before data segments)."""
+    front = dec.string()
+    segs = [dec.blob() for _ in range(dec.u32())] if segments else []
+    if segs:
+        obj = json.loads(front, object_hook=_restorer(
+            segs, front.count(_JSON_NUL) != len(segs)))
+    else:
+        obj = json.loads(front)
+    return obj, segs

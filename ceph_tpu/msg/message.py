@@ -10,14 +10,14 @@ front, after which its byte values (``bytes``, ``bytearray``,
 ``memoryview``, at any depth of a field) ride as raw data segments —
 the reference msgr2's front / data segments
 (``src/msg/async/frames_v2.h``), so bulk payloads cross the wire as
-themselves.
+themselves.  That form is ``core/encoding.py``'s
+``encode_json_segments``, which the object store's WAL record shares.
 """
 
 from __future__ import annotations
 
-import json
-
-from ..core.encoding import DecodeError, Decoder, Encoder
+from ..core.encoding import (Decoder, Encoder, decode_json_segments,
+                             encode_json_segments)
 
 MSG_REGISTRY: dict[int, type["Message"]] = {}
 
@@ -91,39 +91,6 @@ class Message:
         return f"{type(self).__name__}(seq={self.seq})"
 
 
-# A segment reference in the front is {_SEG: index}.  In a message that
-# carries segments, every user dict key starting with NUL gains one more
-# NUL, so no user dict can read as a reference.
-_NUL = "\x00"
-_SEG = _NUL + "seg"
-_BULK = (bytes, bytearray, memoryview)
-_JSON_NUL = "\\u0000"      # how json.dumps writes a NUL
-
-
-def _escape_keys(obj):
-    if isinstance(obj, dict):
-        return {(_NUL + k if isinstance(k, str) and k.startswith(_NUL)
-                 else k): _escape_keys(v) for k, v in obj.items()}
-    if isinstance(obj, (list, tuple)):
-        return [_escape_keys(v) for v in obj]
-    return obj
-
-
-def _restorer(segs: list, escaped: bool):
-    """json object_hook: references → their segments; unescape keys."""
-    def restore(d):
-        if _SEG in d and len(d) == 1:
-            i = d[_SEG]
-            if type(i) is not int or not 0 <= i < len(segs):
-                raise DecodeError(f"bad data segment reference {i!r}")
-            return segs[i]
-        if escaped:
-            return {(k[1:] if k.startswith(_NUL) else k): v
-                    for k, v in d.items()}
-        return d
-    return restore
-
-
 class JsonMessage(Message):
     """Base: one JSON object as payload (the front), then the raw data
     segments its byte values became.  A message with no byte values
@@ -138,38 +105,14 @@ class JsonMessage(Message):
             setattr(self, f, kw.get(f))
 
     def encode_payload(self, enc):
-        segs = []
-
-        def ref(v):
-            if isinstance(v, _BULK):
-                segs.append(v)
-                return {_SEG: len(segs) - 1}
-            raise TypeError(f"{type(v).__name__} is not JSON serializable")
-
-        body = {f: getattr(self, f) for f in self.FIELDS}
-        front = json.dumps(body, default=ref)
-        if segs and front.count(_JSON_NUL) != len(segs):
-            # a NUL besides the references: a user key may start with
-            # one, so escape the keys (the decoder sees the same count)
-            segs.clear()
-            front = json.dumps(_escape_keys(body), default=ref)
-        enc.string(front)
-        enc.u32(len(segs))
+        segs = encode_json_segments(
+            enc, {f: getattr(self, f) for f in self.FIELDS})
         if segs:
             enc.require_compat(2)   # a v1 decoder would keep references
-        for seg in segs:
-            enc.blob_ref(seg)
         self.data_bytes = sum(memoryview(seg).nbytes for seg in segs)
 
     def decode_payload(self, dec, version):
-        front = dec.string()
-        segs = ([dec.blob() for _ in range(dec.u32())]
-                if version >= 2 else [])
-        if segs:
-            data = json.loads(front, object_hook=_restorer(
-                segs, front.count(_JSON_NUL) != len(segs)))
-        else:
-            data = json.loads(front)
+        data, segs = decode_json_segments(dec, segments=version >= 2)
         self.data_bytes = sum(map(len, segs))
         for f in self.FIELDS:
             setattr(self, f, data.get(f))

@@ -3,13 +3,14 @@
 Stands in for the reference's persistent store tier
 (``src/os/bluestore/BlueStore.cc`` commits every mutation through the
 RocksDB WAL; SURVEY.md §6.4).  Every queued Transaction is one
-CRC-framed WAL record (``walog.py``) appended before the in-memory
-apply; ``mount()`` replays the log with the RocksDB torn-tail rule
-shared with ``MonitorDBStore`` (stop at the last parseable record,
-truncate the damage).  This gives the OSD crash-restart durability
-without re-creating BlueStore's block-device allocator — machinery
-whose job (feeding NVMe) has no analog when chunk payloads live in
-HBM-backed JAX arrays.
+CRC-framed WAL record (``walog.py``; its payload is
+``Transaction.to_record``, the byte arguments raw) appended before the
+in-memory apply; ``mount()`` replays the log with the RocksDB torn-tail
+rule shared with ``MonitorDBStore`` (stop at the last parseable
+record, truncate the damage).  This gives the OSD crash-restart
+durability without re-creating BlueStore's block-device allocator —
+machinery whose job (feeding NVMe) has no analog when chunk payloads
+live in HBM-backed JAX arrays.
 
 The commit contract is the reference's (``ObjectStore::
 queue_transaction``): ``on_commit`` fires only once the record is
@@ -33,7 +34,6 @@ deterministic power cuts — see ``crash.py`` for the menu.
 from __future__ import annotations
 
 import collections
-import json
 import os
 import threading
 import time
@@ -71,6 +71,9 @@ class WALStore(MemStore):
         # crash points announce themselves to it before the verdict
         self.flight_recorder = None
         self.on_error: Callable | None = None
+        # optional perf sink (the OSD's logger): wal_append_lat and
+        # wal_wr_bytes for every queued transaction's record
+        self.perf = None
         self.replay_stats: dict | None = None
         self.wal_stats = collections.Counter()
         self._wal = None
@@ -144,7 +147,7 @@ class WALStore(MemStore):
             was_dirty = os.path.exists(self._dirty_path)
             payloads, good_off, tail = walog.scan_path(self._path)
             for payload in payloads:
-                txn = Transaction.from_dict(json.loads(payload.decode()))
+                txn = Transaction.from_record(payload)
                 for op in txn.ops:
                     self._apply_op(op)
             self._records = len(payloads)
@@ -271,14 +274,15 @@ class WALStore(MemStore):
     def queue_transaction(self, txn: Transaction,
                           on_commit: Callable | None = None) -> None:
         err: StoreError | None = None
+        t0 = time.monotonic()
         try:
             with self.lock:
                 if self._failed is not None:
                     raise self._failed
                 self._ensure_open()
-                rec = walog.encode_record(
-                    json.dumps(txn.to_dict(),
-                               separators=(",", ":")).encode())
+                # one buffer, one write: each write call drops and
+                # retakes the interpreter lock, a wait of its own
+                rec = walog.encode_record(*txn.to_record())
                 self._crash_point("kill9")
                 self._crash_point("pre_append")
                 self._crash_point("mid_record", rec)
@@ -296,6 +300,10 @@ class WALStore(MemStore):
                     os.fsync(self._wal.fileno())
                     self._synced_off = self._append_off
                     self.wal_stats["syncs"] += 1
+                perf = self.perf
+                if perf is not None:
+                    perf.tinc("wal_append_lat", time.monotonic() - t0)
+                    perf.inc("wal_wr_bytes", len(rec))
                 self._crash_point("post_fsync_pre_apply")
                 for op in txn.ops:
                     self._apply_op(op)
@@ -535,8 +543,8 @@ class WALStore(MemStore):
                 tmp = self._path + ".compact.tmp"
                 n = 0
                 with open(tmp, "wb") as f:
-                    for payload in self._snapshot_payloads():
-                        f.write(walog.encode_record(payload))
+                    for txn in self._snapshot_txns():
+                        f.write(walog.encode_record(*txn.to_record()))
                         n += 1
                     f.flush()
                     os.fsync(f.fileno())
@@ -562,8 +570,8 @@ class WALStore(MemStore):
         self._notify_error(err)
         raise err
 
-    def _snapshot_payloads(self):
-        """The live state as replayable records, one per collection.
+    def _snapshot_txns(self):
+        """The live state as replayable transactions, one per collection.
         Raw writes only — the conditional dedup opcodes must NOT be
         re-run at replay (the index omap and chunk objects are
         snapshotted verbatim instead, preserving refcounts)."""
@@ -580,5 +588,4 @@ class WALStore(MemStore):
                     txn.setattrs(cid, oid, o.xattrs)
                 if o.omap:
                     txn.omap_setkeys(cid, oid, o.omap)
-            yield json.dumps(txn.to_dict(),
-                             separators=(",", ":")).encode()
+            yield txn

@@ -11,17 +11,26 @@ Reference behavior re-created (``src/os/ObjectStore.h``,
 - reads are synchronous (``read``, ``stat``, ``getattr``,
   ``omap_get``), exactly the reference's split.
 
-A transaction has two serial forms, both decided here: ``to_wire`` for
-the ``MOSDRepOp`` / EC sub-write messages that ship it (the reference
-encodes ``Transaction`` into those payloads), whose byte arguments stay
-``bytes`` and ride as the message's raw data segments; and ``to_dict``,
-plain JSON with byte arguments hex-encoded, for the WAL record.
+A transaction has two serial forms, both decided here: ``to_wire``, the
+op lists as they are, for the ``MOSDRepOp`` / EC sub-write messages
+that ship it (the reference encodes ``Transaction`` into those
+payloads), whose byte arguments ride as the message's raw data
+segments; and ``to_record``, the WAL record's payload: a versioned
+block holding the same op lists as a JSON front, then each byte
+argument as a raw segment (``core.encoding.encode_json_segments``, the
+messages' form).  ``from_record`` also reads the record an older store
+wrote, JSON with byte arguments hex-encoded (``from_dict``).
 """
 
 from __future__ import annotations
 
 import abc
+import json
 from typing import Callable
+
+from ..core.encoding import (Decoder, Encoder, decode_json_segments,
+                             encode_json_segments)
+
 
 class StoreError(OSError):
     """The store's durable backing failed (WAL append/fsync error,
@@ -55,9 +64,10 @@ class Transaction:
     """An ordered opcode stream (reference ``ObjectStore::Transaction``).
 
     Ops are ``[opcode, cid, oid, *args]`` lists; byte payloads are kept
-    as ``bytes`` in memory and on the wire, and hex-encoded only by
-    ``to_dict`` for the WAL record.
+    as ``bytes`` in memory, on the wire and in the WAL record.
     """
+
+    RECORD_VERSION = 1
 
     def __init__(self):
         self.ops: list[list] = []
@@ -161,27 +171,31 @@ class Transaction:
         t.ops = list(data)
         return t
 
-    # -- WAL record form ---------------------------------------------------
-    # Tagged envelopes keep the decode unambiguous regardless of user
-    # key names: a bytes arg becomes {"b": hex}, an attr/omap map
-    # becomes {"d": {key: {"b": hex} | value}}.  (A user attr literally
-    # named "hex"/"b" can no longer be confused with a payload.)
-    def to_dict(self) -> list:
-        out = []
-        for op in self.ops:
-            enc = []
-            for a in op:
-                if isinstance(a, bytes):
-                    enc.append({"b": a.hex()})
-                elif isinstance(a, dict):
-                    enc.append({"d": {
-                        k: {"b": v.hex()} if isinstance(v, bytes) else v
-                        for k, v in a.items()}})
-                else:
-                    enc.append(a)
-            out.append(enc)
-        return out
+    # -- WAL record form: a versioned block of the wire form, its byte
+    # arguments raw segments after the JSON front ----------------------
+    def to_record(self) -> list:
+        """The WAL record's payload as buffers (``Encoder.parts``): each
+        byte argument is one of them, uncopied."""
+        enc = Encoder()
+        with enc.struct_block(self.RECORD_VERSION, self.RECORD_VERSION):
+            encode_json_segments(enc, self.ops)
+        return enc.parts()
 
+    @classmethod
+    def from_record(cls, payload: bytes) -> "Transaction":
+        """A WAL record's payload → the transaction; also the hex-JSON
+        record of an older store, whose payload is a JSON list."""
+        if payload[:1] == b"[":
+            return cls.from_dict(json.loads(payload.decode()))
+        dec = Decoder(payload)
+        with dec.struct_block(cls.RECORD_VERSION) as blk:
+            ops, _ = decode_json_segments(blk.dec)
+        if not isinstance(ops, list):
+            raise ValueError("WAL record holds no op list")
+        return cls.from_wire(ops)
+
+    # The older record: a bytes arg was {"b": hex}, an attr/omap map
+    # {"d": {key: {"b": hex} | value}}.
     @classmethod
     def from_dict(cls, data: list) -> "Transaction":
         t = cls()

@@ -36,10 +36,14 @@ _HEADER = struct.Struct("<2sII")    # magic, payload_len, crc32c
 HEADER_SIZE = _HEADER.size
 
 
-def encode_record(payload: bytes) -> bytes:
-    """One framed WAL record for ``payload``."""
-    payload = bytes(payload)
-    return _HEADER.pack(MAGIC, len(payload), crc32c(payload)) + payload
+def encode_record(*parts) -> bytes:
+    """One framed WAL record for the payload ``b"".join(parts)``: the
+    CRC chains over the parts, which are copied once, into the record."""
+    crc = 0
+    for part in parts:
+        crc = crc32c(part, crc)
+    return b"".join(
+        [_HEADER.pack(MAGIC, sum(map(len, parts)), crc), *parts])
 
 
 def scan_records(buf: bytes) -> tuple[list[bytes], int, dict]:

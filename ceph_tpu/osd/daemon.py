@@ -148,6 +148,12 @@ def _build_osd_perf(name: str):
                    "message encode, frame build and socket write")
     b.add_time_avg("msgr_running_recv_time",
                    "message decode and dispatch")
+    # the object store's WAL (WALStore.perf; reference BlueStore
+    # l_bluestore_kv_*): each queued transaction's record append
+    b.add_time_avg("wal_append_lat",
+                   "queue_transaction entry to its WAL record written "
+                   "and flushed, before the apply")
+    b.add_u64_counter("wal_wr_bytes", "framed WAL record bytes appended")
     return b.create_perf_counters()
 
 
@@ -337,6 +343,7 @@ class OSDaemon(Dispatcher):
         self.batch_engine.store_kick = getattr(self.store, "kick", None)
         if isinstance(self.store, WALStore):
             self.store.on_error = self._on_store_error
+            self.store.perf = self.perf
             self.config.add_observer(
                 "osd_wal_sync_mode",
                 lambda _n, v: self.store.set_sync_mode(v))

@@ -118,7 +118,8 @@ def fsck(path: str, truncate_tail: bool = False) -> dict:
 
     Checks:
       * per-record framing + CRC32C (implicit in the log scan);
-      * every record decodes as JSON and replays as a valid transaction;
+      * every record decodes as a transaction (``Transaction.
+        from_record``, the older hex-JSON record too) and replays;
       * dedup chunk refcounts match live manifests, no orphan chunks;
       * each collection's ``_meta`` info/log omap rows parse as JSON.
     """
@@ -140,7 +141,7 @@ def fsck(path: str, truncate_tail: bool = False) -> dict:
     replayed = 0
     for i, payload in enumerate(payloads):
         try:
-            txn = Transaction.from_dict(json.loads(payload.decode()))
+            txn = Transaction.from_record(payload)
             shadow.queue_transaction(txn)
             replayed += 1
         except Exception as exc:  # noqa: BLE001 — report, keep walking

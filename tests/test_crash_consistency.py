@@ -37,6 +37,11 @@ class TestWalogFraming:
         assert off == len(buf)
         assert tail["status"] == "clean" and tail["lost_bytes"] == 0
 
+    def test_parts_frame_as_their_join(self):
+        parts = (b"front", b"", os.urandom(1000), b"tail")
+        assert walog.encode_record(*parts) == \
+            walog.encode_record(b"".join(parts))
+
     def test_torn_tail_at_every_byte_offset(self):
         """The power-loss contract, exhaustively: cut the last record
         at EVERY byte boundary — header, length field, CRC, payload —
@@ -84,6 +89,104 @@ class TestWalogFraming:
         walog.truncate_tail(p, off)
         out, off2, tail2 = walog.scan_path(p)
         assert out == [b"keep"] and tail2["status"] == "clean"
+
+
+# ---------------------------------------------------------------------------
+# unit: the WAL record's payload (Transaction.to_record) in a WALStore
+# ---------------------------------------------------------------------------
+# a record as the hex-JSON form before the binary one wrote it
+_HEX_JSON_RECORD = (
+    b'[["create_collection","1.0",""],'
+    b'["write","1.0","o",0,{"b":"00ff6f6c64"}],'
+    b'["setattrs","1.0","o",{"d":{"_":{"b":"7b7d"}}}],'
+    b'["omap_setkeys","1.0","o",{"d":{"k":{"b":"76"}}}]]')
+
+
+class TestWALRecordForm:
+    DATA = bytes(range(256)) * 16
+
+    def _write(self, path, n=2):
+        s = WALStore(path)
+        s.mkfs()
+        s.apply_transaction(Transaction().create_collection("1.0"))
+        for i in range(n):
+            s.apply_transaction(
+                Transaction().write("1.0", f"o{i}", 0, self.DATA)
+                .setattrs("1.0", f"o{i}", {"\x00k": bytes([i])}))
+        s.umount()
+
+    def test_mount_replays_binary_log(self, tmp_path):
+        path = str(tmp_path / "s.wal")
+        self._write(path)
+        payloads, _, tail = walog.scan_path(path)
+        assert tail["status"] == "clean" and len(payloads) == 3
+        assert all(not p.startswith(b"[") for p in payloads)
+        assert self.DATA in payloads[1]          # raw, not hex
+        s = WALStore(path)
+        s.mount()
+        assert s.replay_stats["records"] == 3
+        for i in range(2):
+            assert s.read("1.0", f"o{i}") == self.DATA
+            assert s.getattr("1.0", f"o{i}", "\x00k") == bytes([i])
+        s.umount()
+
+    @pytest.mark.parametrize("keep", [
+        walog.HEADER_SIZE - 1, walog.HEADER_SIZE + 3,
+        walog.HEADER_SIZE + 40, 0.5, -1])
+    def test_torn_binary_record_truncated(self, tmp_path, keep):
+        path = str(tmp_path / "s.wal")
+        self._write(path, n=1)
+        good = os.path.getsize(path)
+        rec = walog.encode_record(
+            *Transaction().write("1.0", "torn", 0, self.DATA).to_record())
+        cut = int(len(rec) * keep) if isinstance(keep, float) else keep
+        with open(path, "ab") as f:
+            f.write(rec[:cut])
+        s = WALStore(path)
+        s.mount()
+        assert s.replay_stats["tail"]["status"] == "torn"
+        assert s.read("1.0", "o0") == self.DATA
+        assert not s.exists("1.0", "torn")
+        s.umount()
+        assert os.path.getsize(path) == good       # the tail is gone
+
+    def test_hex_json_record_still_replays(self, tmp_path):
+        """A log an older store wrote mounts; new writes append binary
+        records after its hex-JSON ones, and both replay."""
+        from ceph_tpu.tools import objectstore_tool
+        path = str(tmp_path / "s.wal")
+        with open(path, "wb") as f:
+            f.write(walog.encode_record(_HEX_JSON_RECORD))
+        s = WALStore(path)
+        s.mount()
+        assert s.read("1.0", "o") == b"\x00\xffold"
+        assert s.getattr("1.0", "o", "_") == b"{}"
+        assert s.omap_get("1.0", "o") == {"k": b"v"}
+        s.apply_transaction(Transaction().write("1.0", "o", 5, b"new"))
+        s.umount()
+        s2 = WALStore(path)
+        s2.mount()
+        assert s2.replay_stats["records"] == 2
+        assert s2.read("1.0", "o") == b"\x00\xffoldnew"
+        s2.umount()
+        rep = objectstore_tool.fsck(path)
+        assert rep["records_replayed"] == 2 and not rep["issues"]
+
+    def test_compaction_writes_binary_records(self, tmp_path):
+        path = str(tmp_path / "s.wal")
+        with open(path, "wb") as f:
+            f.write(walog.encode_record(_HEX_JSON_RECORD))
+        s = WALStore(path)
+        s.mount()
+        s.compact()
+        s.umount()
+        payloads, _, _ = walog.scan_path(path)
+        assert payloads and not any(p.startswith(b"[") for p in payloads)
+        s2 = WALStore(path)
+        s2.mount()
+        assert s2.read("1.0", "o") == b"\x00\xffold"
+        assert s2.omap_get("1.0", "o") == {"k": b"v"}
+        s2.umount()
 
 
 # ---------------------------------------------------------------------------

@@ -6,11 +6,11 @@ WAL-specific durability cases (replay, torn tail) the reference covers
 via BlueStore fsck/mount tests.
 """
 
-import json
+import hashlib
 
 import pytest
 
-from ceph_tpu.os_store import MemStore, Transaction, WALStore
+from ceph_tpu.os_store import MemStore, Transaction, WALStore, walog
 
 
 @pytest.fixture(params=["mem", "wal"])
@@ -125,11 +125,92 @@ def test_transaction_wire_roundtrip(store):
      .omap_rmkeys(CID, "o", ["gone"]) \
      .zero(CID, "o", 0, 1).truncate(CID, "o", 4) \
      .clone(CID, "o", "o2").remove(CID, "o2").touch(CID, "t")
-    wire = json.loads(json.dumps(t.to_dict()))
-    t2 = Transaction.from_dict(wire)
+    t2 = Transaction.from_record(b"".join(t.to_record()))
     assert t2.ops == t.ops
     store.apply_transaction(t2)
     assert store.read(CID, "o") == b"\0\0\0\x00"
+
+
+# a transaction of each op kind; the maps carry bytes and user keys that
+# start with NUL, as the segment codec escapes them
+_OP_KINDS = {
+    "touch": lambda t: t.touch(CID, "o"),
+    "write": lambda t: t.write(CID, "o", 7, b"\x00\xffraw"),
+    "write_empty": lambda t: t.write(CID, "o", 0, b""),
+    "zero": lambda t: t.zero(CID, "o", 1, 2),
+    "truncate": lambda t: t.truncate(CID, "o", 3),
+    "remove": lambda t: t.remove(CID, "o"),
+    "setattrs": lambda t: t.setattrs(
+        CID, "o", {"_": b"{}", "\x00seg": b"\x00", "\x00k": b"v",
+                   "plain": b"\xfe"}),
+    "rmattr": lambda t: t.rmattr(CID, "o", "\x00k"),
+    "omap_setkeys": lambda t: t.omap_setkeys(
+        CID, "o", {"\x00\x00seg": b"x", "k": b"\x00" * 3}),
+    "omap_rmkeys": lambda t: t.omap_rmkeys(CID, "o", ["\x00seg", "k"]),
+    "clone": lambda t: t.clone(CID, "o", "o2"),
+    "coll_move": lambda t: t.coll_move(CID, "o", "1.1"),
+    "create_collection": lambda t: t.create_collection(CID),
+    "remove_collection": lambda t: t.remove_collection(CID),
+    "dedup_ingest": lambda t: t.dedup_ingest(CID, "fp", b"chunk\x00"),
+    "dedup_release": lambda t: t.dedup_release(CID, "fp"),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(_OP_KINDS))
+def test_record_roundtrip_every_op_kind(kind):
+    t = _OP_KINDS[kind](Transaction())
+    got = Transaction.from_record(b"".join(t.to_record()))
+    assert got.ops == t.ops
+    for a, b in zip(got.ops[0], t.ops[0]):
+        assert type(a) is type(b)
+
+
+def test_record_keeps_bytes_raw():
+    """A 512 KiB shard write's record is its bytes plus a small front:
+    no hex (which would double it)."""
+    data = bytes(range(256)) * 2048
+    t = Transaction().write(CID, "o", 0, data)
+    parts = t.to_record()
+    assert any(p is data for p in parts)      # the shard, uncopied
+    assert len(walog.encode_record(*parts)) <= len(data) + 4096
+
+
+# JsonMessage's bytes on the wire, pinned at their form before the codec
+# moved to core.encoding (sha256 of the encoding, seq 5)
+_MSG_DIGESTS = {
+    "no_bytes":
+        "5742f6e479ae3107466e81e0248b096b471252b98f222d3efa1ef3dc61f5f2f5",
+    "bytes":
+        "555c113e108423894a52d45dfb476c47d56f47ab11c467d0e13d7e6bfab07f81",
+    "nul_keys":
+        "c2a6a9a7344ca1120f89e36fb5a8fda1ecf8c1b5fc53a5dc21e03946de0a0e3d",
+    "txn":
+        "629168338141f5b11d83fcf2b0ebcd9aaddb009d762d63c695539e4eb6561bf8",
+}
+
+
+@pytest.mark.parametrize("name", sorted(_MSG_DIGESTS))
+def test_json_message_bytes_unchanged(name):
+    from ceph_tpu.mon.messages import MMonCommand
+    from ceph_tpu.osd import messages as M
+    msg = {
+        "no_bytes": lambda: MMonCommand(tid=9, cmd={
+            "prefix": "osd pool create", "pool": "p", "pg_num": 8}),
+        "bytes": lambda: M.MOSDOp(
+            tid=1, client="c", pgid="1.0", oid="o", epoch=3,
+            ops=[{"op": "write_full", "data": b"\x00\x01payload"}],
+            flags=0),
+        "nul_keys": lambda: M.MOSDOp(tid=2, ops=[
+            {"op": "x", "kv": {"\x00seg": 0, "\x00k": b"v"}},
+            {"op": "write", "data": b"bulk"}]),
+        "txn": lambda: M.MOSDECSubOpWrite(
+            reqid="c:1", pgid="1.0", shard=0, epoch=4,
+            txn=[["write", "1.0s0", "o", 0, b"chunk"],
+                 ["setattrs", "1.0s0", "o", {"_": b"{}"}]],
+            version=[4, 1]),
+    }[name]()
+    msg.seq = 5
+    assert hashlib.sha256(msg.encode()).hexdigest() == _MSG_DIGESTS[name]
 
 
 class TestWALDurability:
