@@ -116,7 +116,7 @@ class FlightRecorder:
         self._size = 0
         self._marks: deque = deque(maxlen=4096)
         self._prev_perf: dict = {}
-        # overhead accounting (bench's blackbox_overhead_pct source)
+        # overhead accounting
         self._records = 0
         self._bytes = 0
         self._io_s = 0.0

@@ -17,8 +17,8 @@ mutes auto-expire when the check clears or worsens (count increase),
 sticky ones only on TTL expiry or explicit unmute — the reference's
 semantics.
 
-``evaluate_checks`` is a pure function of a ``HealthContext`` so
-bench.py can time a 4k-OSD evaluation without a Monitor.
+``evaluate_checks`` is a pure function of a ``HealthContext``, so a
+caller can run it on a large map without a Monitor.
 """
 
 from __future__ import annotations

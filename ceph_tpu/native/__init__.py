@@ -128,8 +128,7 @@ def ensure_built(timeout_s: float = 180.0) -> bool:
     machine rebuilds instead of loading a foreign-ISA binary, and on
     the same host ``make`` is a no-op.  Run before the first load — a
     loaded library cannot be swapped.  Bounded; a failed build leaves
-    `available()` False (the bench's numpy denominator, labelled as
-    such).
+    `available()` False.
     """
     if _lib is None:
         import subprocess

@@ -26,8 +26,6 @@ shuffles on TPU):
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -101,26 +99,29 @@ class GFLinear:
     [batch..., k, n] uint8 returns [batch..., m, n] uint8.
 
     Backends:
-    - ``"pallas"`` — the fused VMEM kernel v2
+    - ``"pallas"`` — the fused VMEM kernel
       (`ceph_tpu.ops.gf_pallas2`), the TPU production path: bytes
       processed 4-per-lane as i32 words, bit-planes expanded in VMEM,
       a 256-deep (at k=8) GF(2) matmul on the MXU, parity packed back
       to words — one HBM read of the data, one write of the parity;
-    - ``"pallas-v1"`` — the original uint8-layout fused kernel
-      (`ceph_tpu.ops.gf_pallas`), kept for the old-vs-new roofline
-      comparison in bench.py;
+    - ``"pallas-interpret"`` — the same kernel in interpret mode, for
+      CPU byte-exactness tests;
     - ``"xla"`` — the dot_general bitmatrix composition above (works on
       any backend; what CPU tests run);
-    - ``"auto"`` (default) — pallas (v2) on TPU, xla elsewhere.
-    ``*-interpret`` variants run the pallas kernels in interpret mode
-    for CPU byte-exactness tests.
+    - ``"auto"`` (default) — pallas on TPU, xla elsewhere.
+    Any other name raises ``ValueError``.
     """
+
+    BACKENDS = ("auto", "xla", "pallas", "pallas-interpret")
 
     def __init__(self, coding: np.ndarray, use_bits: bool = True,
                  backend: str = "auto"):
         self.coding = np.asarray(coding, dtype=np.uint8)
         self.m, self.k = self.coding.shape
         self.use_bits = use_bits
+        if backend not in self.BACKENDS:
+            raise ValueError(f"unknown GFLinear backend {backend!r}; "
+                             f"one of {self.BACKENDS}")
         if backend == "auto":
             backend = ("pallas" if jax.default_backend() == "tpu"
                        and use_bits else "xla")
@@ -183,14 +184,6 @@ class GFLinear:
             return gf_matmul_pallas2(
                 self._mat, data, self.m,
                 interpret=self.backend == "pallas-interpret",
-                bdmats=self._bdmats)
-        if self.backend in ("pallas-v1", "pallas-v1-interpret"):
-            from .gf_pallas import gf_matmul_pallas
-            if not hasattr(self, "_bdmats"):
-                self._bdmats = {}
-            return gf_matmul_pallas(
-                self._mat, data, self.m,
-                interpret=self.backend == "pallas-v1-interpret",
                 bdmats=self._bdmats)
         if self.use_bits:
             return gf_matmul_bits(self._mat, data, self.m)

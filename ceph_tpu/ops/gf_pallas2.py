@@ -1,14 +1,11 @@
-"""Fused GF(2^8) matmul Pallas kernel v2 — bit-sliced i32 lanes.
+"""Fused GF(2^8) matmul Pallas kernel — bit-sliced i32 lanes.
 
-Why a v2: the v1 kernel (`gf_pallas.py`) measured ~7.5 GB/s on v5e and
-was flat across stripe grouping — its bottleneck was never the MXU
-(~9% contraction fill) but the VPU expand/pack work and the layout:
-every uint8 array with k/m sublanes pays (32, 128) tiling padding, and
-int8 elementwise ops occupy full 32-bit VPU lanes anyway.  v2 keeps
-the same math (GF(2^8) multiply-accumulate == GF(2) bitmatrix matmul,
-the reference's ``galois_w08_region_multiply`` region loop behind
-``src/erasure-code/jerasure``; SURVEY.md §4.2) but restructures the
-data movement:
+GF(2^8) multiply-accumulate is a GF(2) bitmatrix matmul (the
+reference's ``galois_w08_region_multiply`` region loop behind
+``src/erasure-code/jerasure``; SURVEY.md §4.2).  The layout is chosen
+for the TPU: a uint8 array with k or m sublanes pays (32, 128) tiling
+padding, and int8 elementwise ops occupy full 32-bit VPU lanes anyway.
+So the kernel moves data as int32 words:
 
     bytes are processed 4-per-lane as int32 words
       data tile  [k, TN/4] int32            (native (8,128) i32 tiling)
@@ -16,29 +13,30 @@ data movement:
                                              bit j%8 — 2 VPU ops/plane)
       -> GF(2) matmul on the MXU            ([32m, 32k] x [32k, TN/4],
                                              256-deep contraction @k=8:
-                                             2x the MXU's native depth,
-                                             vs 64 = 50% stalls in v1)
+                                             2x the MXU's native depth)
       -> mask + weighted re-pack            ([m, TN/4] int32 words)
 
     so every array in the pipeline has a 32-bit or sublane-aligned
     int8 layout — no uint8 relayouts — and HBM still moves only data
     once in, parity once out.
 
-The GF(2) matrix is the v1 bitmatrix block-diagonalized 4x over byte
-position: byte b of a word only ever multiplies into byte b of the
-parity word, so block b maps plane rows [b*8k, (b+1)*8k) to output
-rows [b*8m, (b+1)*8m).  Word-internal byte order therefore cancels:
-whatever order `lax.bitcast_convert_type` packs bytes into a word, the
-same order unpacks the parity word, and GF acts bytewise.
+The GF(2) matrix is the bit-layout matrix (`gf_jax._bit_layout_matrix`)
+block-diagonalized 4x over byte position: byte b of a word only ever
+multiplies into byte b of the parity word, so block b maps plane rows
+[b*8k, (b+1)*8k) to output rows [b*8m, (b+1)*8m).  Word-internal byte
+order therefore cancels: whatever order `lax.bitcast_convert_type`
+packs bytes into a word, the same order unpacks the parity word, and GF
+acts bytewise.
 
-Mosaic constraints honored from v1's production runs: no vector
-shifts on sub-32-bit ints — bit extraction is AND + compare, packing
-is multiply-add (weights wrap through int32, bit 31 included); traced
-under `jax.enable_x64(False)`.
+Mosaic constraints: no vector shifts on sub-32-bit ints — bit
+extraction is AND + compare, packing is multiply-add (weights wrap
+through int32, bit 31 included); traced under `jax.enable_x64(False)`.
 
 Byte-exactness: `tests/test_gf_pallas2.py` (interpret mode vs the
-NumPy oracle and the XLA path); on real TPU, `bench.py` verifies
-parity bytes before any timing.
+NumPy oracle and the XLA path).  On the TPU,
+`chip_smoke.py::phase_ec_kernels` compares the words kernel's encode
+and decode with the oracle and asserts `GFLinear`'s backend is
+``"pallas"``.
 """
 
 from __future__ import annotations
@@ -64,7 +62,7 @@ _BIT_MASK = [int(np.int32(np.uint32(1 << j))) for j in range(32)]
 
 
 def block_diag4(bitmat: np.ndarray) -> np.ndarray:
-    """v1 bit-layout matrix [8m, 8k] -> word-sliced [32m, 32k] int8:
+    """Bit-layout matrix [8m, 8k] -> word-sliced [32m, 32k] int8:
     one identical block per in-word byte position."""
     m8, k8 = bitmat.shape
     out = np.zeros((4 * m8, 4 * k8), dtype=np.int8)

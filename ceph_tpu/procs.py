@@ -30,9 +30,8 @@ Contracts:
 - **One process per chip**: procs-mode daemons always run their
   batch-engine lanes on the CPU — ``spawn_daemon`` sets
   ``JAX_PLATFORMS=cpu`` in every child's environment.  A chip belongs
-  to one process at a time, and the parent of a procs cluster (a test,
-  or ``bench.py``'s measurement child) may already hold it; a child
-  that tried to claim it would fail or hang.
+  to one process at a time, and the parent of a procs cluster may
+  already hold it; a child that tried to claim it would fail or hang.
 - **kill -9 semantics**: children run with ``CEPH_TPU_PROC_DAEMON=1``
   in the environment, which arms the ``kill9`` crash point in
   ``WALStore`` to deliver a real ``os.kill(getpid(), SIGKILL)``.
@@ -56,7 +55,7 @@ import threading
 import time
 from dataclasses import dataclass, field
 
-DAEMON_KINDS = ("mon", "osd", "mgr", "workload", "msgr_victim")
+DAEMON_KINDS = ("mon", "osd", "mgr", "msgr_victim")
 
 # child-process marker: WALStore's kill9 crash point delivers a real
 # SIGKILL only when this is set (threaded mode degrades it to the
@@ -248,8 +247,8 @@ def spawn_daemon(spec: DaemonSpec, *, retries: int = 2,
     log_path = os.path.join(run_dir, f"{spec.name}.log")
     env = dict(os.environ)
     env[PROC_ENV] = "1"
-    # one process per chip: the parent (a test, or bench.py's child)
-    # may hold it, so the daemon's lanes run on the CPU
+    # one process per chip: the parent may hold it, so the daemon's
+    # lanes run on the CPU
     env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = _repo_root() + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
@@ -316,96 +315,6 @@ def write_ready(spec: DaemonSpec) -> None:
         f.flush()
         os.fsync(f.fileno())
     os.replace(tmp, spec.ready_path)
-
-
-# -- open-loop rados ramp (shared by bench threaded leg + workload child)
-def run_rados_ramp(monmap, *, seed: int = 0, pool: str = "ramp",
-                   pool_size: int = 2, pg_num: int = 8,
-                   rates=(50, 100, 200, 400, 800, 1600),
-                   step_duration: float = 2.0,
-                   slo_p99_ms: float = 250.0,
-                   object_kb: int = 16, n_objects: int = 64,
-                   workers: int = 8) -> dict:
-    """Rados-level ramp-to-collapse: step the offered rate through a
-    geometric ladder of seeded open-loop write/read mixes and find the
-    knee — the last rate where p99 holds the SLO, goodput keeps ≥90%
-    of offered, and no op errors.  Same knee definition as
-    ``workload.scenarios.ramp_to_collapse`` but driven straight at
-    librados (no RGW front door), so it runs identically in-process
-    (threaded leg) and as a ``workload`` daemon process (procs leg).
-    """
-    import random as _random
-
-    from .mon.monitor import MonMap
-    from .osdc.librados import Rados
-    from .workload.generator import (RBD_READ, RBD_WRITE, LoadGenerator,
-                                     OpMix, TenantProfile)
-    from .workload.slo import SLOTracker
-
-    if isinstance(monmap, dict):
-        monmap = MonMap.from_dict(monmap)
-    r = Rados(monmap, name=f"client.ramp{seed}").connect()
-    try:
-        if pool not in r.list_pools():
-            r.create_pool(pool, pg_num=pg_num, size=pool_size)
-        io = r.open_ioctx(pool)
-        payload = _random.Random(seed).randbytes(object_kb << 10)
-        for i in range(n_objects):
-            io.write_full(f"ramp-{i}", payload)
-
-        def execute(op):
-            oid = f"ramp-{op.seq % n_objects}"
-            if op.op_class == RBD_WRITE:
-                io.write_full(oid, payload)
-            else:
-                io.read(oid)
-
-        mix = OpMix({RBD_WRITE: 1, RBD_READ: 1})
-        steps, knee, collapse = [], None, None
-        for rate in rates:
-            tracker = SLOTracker({"*": slo_p99_ms})
-            prof = TenantProfile("ramp", rate, kind="poisson",
-                                 mix=mix, size=object_kb << 10,
-                                 seed=seed)
-            gen = LoadGenerator([prof], execute,
-                                duration=step_duration,
-                                workers=workers, tracker=tracker)
-            stop = threading.Event()
-
-            def _tick():
-                while not stop.wait(0.25):
-                    tracker.evaluate()
-            t = threading.Thread(target=_tick, daemon=True)
-            t.start()
-            open_loop = gen.run()
-            stop.set()
-            t.join(timeout=2)
-            rep = tracker.report()
-            p99 = max((lane["p99_ms"]
-                       for t_ in rep["tenants"].values()
-                       for lane in t_.values()), default=0.0)
-            holds = (p99 <= slo_p99_ms
-                     and rep["goodput_ops"]
-                     >= 0.9 * rep["offered_rate"]
-                     and open_loop["errors"] == 0)
-            steps.append({"rate": rate, "p99_ms": round(p99, 2),
-                          "goodput_ops": round(rep["goodput_ops"], 1),
-                          "offered_rate":
-                              round(rep["offered_rate"], 1),
-                          "errors": open_loop["errors"],
-                          "drift_pct":
-                              round(open_loop["drift_pct"], 2),
-                          "holds": holds})
-            if holds:
-                knee = rate
-            else:
-                collapse = rate
-                break
-        return {"seed": seed, "slo_p99_ms": slo_p99_ms,
-                "knee_ops_per_sec": knee,
-                "collapse_ops_per_sec": collapse, "steps": steps}
-    finally:
-        r.shutdown()
 
 
 # -- child entrypoint -----------------------------------------------------
@@ -485,23 +394,6 @@ def _build_mgr(spec: DaemonSpec):
     return mgr
 
 
-def _run_workload(spec: DaemonSpec) -> int:
-    """Open-loop generator as its own process: ready first (the parent
-    tracks the PID), then drive the ramp, then write the report JSON
-    and exit 0 — the parent collects via wait() + result file."""
-    write_ready(spec)
-    params = dict(spec.extra.get("ramp") or {})
-    result_path = spec.extra.get("result_path")
-    report = run_rados_ramp(spec.monmap,
-                            seed=int(spec.fault_seed or 0), **params)
-    if result_path:
-        tmp = result_path + ".tmp"
-        with open(tmp, "w") as f:
-            json.dump(report, f)
-        os.replace(tmp, result_path)
-    return 0
-
-
 def _build_msgr_victim(spec: DaemonSpec):
     """Accept-side messenger that records every MGenericReply.result
     (one int per line, flushed) to extra["out_path"] — the kill-the-
@@ -539,8 +431,6 @@ def main(argv: list[str] | None = None) -> int:
 
     signal.signal(signal.SIGTERM, _on_sigterm)
     signal.signal(signal.SIGINT, _on_sigterm)
-    if spec.kind == "workload":
-        return _run_workload(spec)
     builders = {"mon": _build_mon, "osd": _build_osd,
                 "mgr": _build_mgr, "msgr_victim": _build_msgr_victim}
     daemon = builders[spec.kind](spec)

@@ -122,8 +122,7 @@ def run(argv=None) -> dict:
         t0 = time.perf_counter()
         for _ in range(args.iterations):
             # materialize EVERY iteration: fetching the parity fences
-            # the work (the host transfer is included; bench.py's
-            # chained-jit loop is the transfer-free metric of record)
+            # the work (the host transfer is included)
             np.asarray(jax_block(encode_once()))
             total_bytes += batch * k * chunk
         elapsed = time.perf_counter() - t0

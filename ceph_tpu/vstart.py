@@ -959,9 +959,7 @@ class MiniCluster:
         "until": fn(event)->bool|None, "timeout": s}``: fire the
         action, then (if `until` is given) consume the live `ceph -w`
         event stream until the predicate matches.  Returns per-phase
-        wall-clock timings — the bench stretch leg reads
-        ``site_failover_detect_s`` and ``site_heal_convergence_s``
-        straight out of this report."""
+        wall-clock timings."""
         report = []
         with self.watch() as w:
             for ph in phases:

@@ -2,8 +2,8 @@
 
 Each scenario stands up (or borrows) a MiniCluster, fronts it with
 the concurrent RGW gateway, drives a seeded open-loop schedule, and
-returns a JSON-safe report — the same dicts `bench.py::_frontdoor_leg`
-asserts on and `mgr_command("slo ingest")` publishes to the exporter.
+returns a JSON-safe report — the dicts `mgr_command("slo ingest")`
+publishes to the exporter.
 
 - `steady_state`: one tenant at a fixed offered rate; the baseline.
 - `ramp_to_collapse`: geometric rate ramp until the p99 SLO breaks or
@@ -433,8 +433,8 @@ def regime_shift(*, cluster=None, base_rate: float = 60.0,
 
     Returns per-phase p99/goodput/violation numbers plus
     ``sustained_MBps`` (goodput bytes over total measured time) and
-    ``worst_p99_ms`` — the two scalars the bench compares between
-    static configs and the controller.  Seeds are per-phase
+    ``worst_p99_ms`` — the two scalars to compare between static
+    configs and the controller.  Seeds are per-phase
     (``seed + phase_index``); fingerprints make replays checkable."""
     rig = _Rig(cluster, tenants=("shift",), size=size)
     try:

@@ -5,8 +5,8 @@
  * (src/erasure-code/jerasure/gf-complete, galois_w08_region_multiply);
  * the TPU path replaces it with MXU matmuls, and THIS library is the
  * native-code analog for host-side work: the CPU fallback inside the
- * libec plugin bridge, the baseline denominator for bench.py, and the
- * byte-exactness oracle reachable from C++ without Python.
+ * libec plugin bridge and the byte-exactness oracle reachable from C++
+ * without Python.
  *
  * Field: GF(256), primitive polynomial 0x11d — identical tables to
  * ceph_tpu/ops/gf.py (tests assert this).

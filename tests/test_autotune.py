@@ -1,8 +1,7 @@
 """Self-tuning data plane: the mgr autotuner engine (seeded
 determinism, guarded rollback, bounds), the module's command surface
 and actuation path, the telemetry spine's SLO pressure rings, and a
-CPU-lenient regime-shift parity smoke (the strict parity bar rides in
-``bench.py::_autotune_leg``)."""
+CPU-lenient regime-shift parity smoke."""
 
 import json
 import time
@@ -231,10 +230,9 @@ def test_recovery_max_active_live_observer():
 
 def test_regime_shift_parity_smoke():
     """The tier-1 parity smoke: one static config vs the autotuned
-    run on a short regime shift (no recovery storm — that phase rides
-    in the bench leg).  The bar is deliberately lenient: this guards
-    the wiring (controller must not melt throughput), the real parity
-    bar is bench-owned."""
+    run on a short regime shift (no recovery storm).  The bar is
+    deliberately lenient: this guards the wiring (controller must not
+    melt throughput)."""
     from ceph_tpu.vstart import MiniCluster
     from ceph_tpu.workload.scenarios import regime_shift
 

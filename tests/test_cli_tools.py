@@ -173,98 +173,3 @@ class TestCephDf:
             assert "health:" in capsys.readouterr().out
         finally:
             r.shutdown()
-
-
-class TestBenchCompare:
-    """tools/bench_compare — the perf-trajectory gate (pure files,
-    no cluster)."""
-
-    def _write(self, tmp_path, name, parsed):
-        p = tmp_path / name
-        p.write_text(json.dumps({"n": 1, "parsed": parsed}))
-        return str(p)
-
-    def test_direction_aware_regressions_and_check(self, tmp_path,
-                                                   capsys):
-        from ceph_tpu.tools import bench_compare
-        old = self._write(tmp_path, "BENCH_r01.json", {
-            "encode_GBps": 100.0,       # higher-is-better: drops
-            "p99_ms": 10.0,             # lower-is-better: rises
-            "trace_overhead_pct": 8.0,  # lower-is-better: improves
-            "goodput_ops": 50.0,        # small move: inside threshold
-        })
-        new = self._write(tmp_path, "BENCH_r02.json", {
-            "encode_GBps": 80.0,
-            "p99_ms": 14.0,
-            "trace_overhead_pct": 2.0,
-            "goodput_ops": 51.0,
-        })
-        assert bench_compare.main([old, new, "--json"]) == 0
-        rep = json.loads(capsys.readouterr().out)
-        assert sorted(rep["regressions"]) == ["encode_GBps",
-                                              "p99_ms"]
-        verdicts = {r["metric"]: r["verdict"] for r in rep["rows"]}
-        assert verdicts["trace_overhead_pct"] == "improved"
-        assert verdicts["goodput_ops"] == "ok"
-        # --check turns regressions into a non-zero exit
-        assert bench_compare.main([old, new, "--check"]) == 1
-        capsys.readouterr()
-        # latest-pair discovery walks the directory
-        assert bench_compare.main(
-            ["--dir", str(tmp_path), "--check"]) == 1
-        head = capsys.readouterr().out.splitlines()[0]
-        assert "BENCH_r01.json" in head and "BENCH_r02.json" in head
-
-    def test_throughput_suffix_is_higher_is_better(self, tmp_path,
-                                                   capsys):
-        """``*_ops_per_sec``/``*_mb_per_sec`` end in a time unit but
-        are throughput: halving is a regression, doubling is an
-        improvement — not the other way around."""
-        from ceph_tpu.tools import bench_compare
-        old = self._write(tmp_path, "BENCH_r01.json", {
-            "sustained_ops_per_sec": 1000.0,      # halves: regressed
-            "scrub_digest_mb_per_sec": 50.0,      # doubles: improved
-            "knee_ops_per_sec_threaded": 400.0,   # rises: improved
-            "heal_s": 4.0,                        # time suffix: rises
-        })
-        new = self._write(tmp_path, "BENCH_r02.json", {
-            "sustained_ops_per_sec": 500.0,
-            "scrub_digest_mb_per_sec": 100.0,
-            "knee_ops_per_sec_threaded": 480.0,
-            "heal_s": 8.0,
-        })
-        assert bench_compare.main([old, new, "--json",
-                                   "--check"]) == 1
-        rep = json.loads(capsys.readouterr().out)
-        verdicts = {r["metric"]: r["verdict"] for r in rep["rows"]}
-        assert verdicts["sustained_ops_per_sec"] == "regressed"
-        assert verdicts["scrub_digest_mb_per_sec"] == "improved"
-        assert verdicts["knee_ops_per_sec_threaded"] == "improved"
-        assert verdicts["heal_s"] == "regressed"
-        assert sorted(rep["regressions"]) == [
-            "heal_s", "sustained_ops_per_sec"]
-        # the throughput doubling alone must PASS --check
-        old2 = self._write(tmp_path, "BENCH_r03.json",
-                           {"sustained_ops_per_sec": 500.0})
-        new2 = self._write(tmp_path, "BENCH_r04.json",
-                           {"sustained_ops_per_sec": 1000.0})
-        assert bench_compare.main([old2, new2, "--check"]) == 0
-        capsys.readouterr()
-
-    def test_clean_diff_passes_check(self, tmp_path, capsys):
-        from ceph_tpu.tools import bench_compare
-        old = self._write(tmp_path, "BENCH_r01.json",
-                          {"encode_GBps": 100.0, "p99_ms": 10.0})
-        new = self._write(tmp_path, "BENCH_r02.json",
-                          {"encode_GBps": 103.0, "p99_ms": 9.8,
-                           "attribution_overhead_pct": 0.4})
-        assert bench_compare.main([old, new, "--check"]) == 0
-        out = capsys.readouterr().out
-        assert "0 regression(s)" in out
-        assert "attribution_overhead_pct (new metric)" in out
-
-    def test_missing_inputs_fail_cleanly(self, tmp_path, capsys):
-        from ceph_tpu.tools import bench_compare
-        assert bench_compare.main(
-            ["--dir", str(tmp_path)]) == 2
-        assert "bench_compare:" in capsys.readouterr().err

@@ -23,10 +23,10 @@ The contract mirrors the batch-engine suite's shape:
 
 import io as _io
 import json
-import pathlib
 import sys
 import time
 
+import numpy as np
 import pytest
 
 from ceph_tpu.compress import dedup as dd
@@ -474,18 +474,74 @@ class TestEngineOnOffIdentity:
         assert stored[False] == stored[True]
 
 
-# ---------------------------------------------------------------- bench
+# ------------------------------------------------- lanes on a write mix
 
 def test_bench_efficiency_leg_cpu_smoke():
-    """The bench `_efficiency_leg` with its CPU-sized corpus fits the
-    tier-1 budget and meets the acceptance ratios."""
-    root = str(pathlib.Path(__file__).resolve().parent.parent)
-    if root not in sys.path:
-        sys.path.insert(0, root)
-    import bench
-    res = bench._efficiency_leg(False)
-    assert res["bit_identical"]
-    assert res["compression_ratio"] > 1.5
-    assert res["dedup_ratio"] > 2.0
-    assert res["passthrough"] >= 1
-    assert res["compress_effective_GBps"] > 0
+    """A write mix through one BatchEngine's compress lane and a
+    duplicated stream through its fingerprint lane: every sealed blob
+    expands to its payload, incompressible payloads pass through,
+    batched results equal an engine-off replay, and the mix meets the
+    compression (> 1.5x) and dedup (> 2x) ratios."""
+    rng = np.random.default_rng(19)
+    codec = create_codec("rle")
+    size, nobj = 256 << 10, 24
+    payloads = []
+    for i in range(nobj):
+        if i % 8 == 7:      # incompressible tail: must pass through
+            payloads.append(
+                rng.integers(0, 256, size, np.uint8).tobytes())
+        else:               # run-structured (device logs, zero pages)
+            run = int(rng.integers(16, 128))
+            vals = rng.integers(0, 256, size // run + 1, np.uint8)
+            payloads.append(np.repeat(vals, run)[:size].tobytes())
+
+    eng = BatchEngine("eff", flush_ms=2.0, max_ops=64,
+                      max_bytes=64 << 20)
+    off = BatchEngine("eff-off", enabled=False)
+    try:
+        eng.submit_compress(codec, payloads[0])     # warm the bucket
+        eng.drain()
+        for key in list(eng.stats):
+            eng.stats[key] = 0
+        comps = [eng.submit_compress(codec, p) for p in payloads]
+        eng.drain()
+        assert all(c.done() and c.error is None for c in comps)
+        passthrough = 0
+        for c, p in zip(comps, payloads):
+            blob, hdr = c.result()
+            if hdr is None:
+                passthrough += 1
+                assert bytes(blob) == p
+            else:
+                assert eng.decompress(blob, hdr) == p
+        assert passthrough >= nobj // 8      # the 3 incompressible
+        ratio = (eng.stats["comp_bytes_in"]
+                 / max(1, eng.stats["comp_bytes_out"]))
+        assert ratio > 1.5
+        # engine-off bit-identity: same codec path, no batching
+        for j in (0, 7, nobj - 1):
+            assert comps[j].result() == \
+                off.submit_compress(codec, payloads[j]).result()
+
+        # 4 shuffled copies of each base block: the CDC chunker must
+        # converge on identical fingerprints for identical content
+        chunker = Chunker(avg_size=4096)
+        blocks = [rng.integers(0, 256, 64 << 10, np.uint8).tobytes()
+                  for _ in range(4)]
+        order = list(range(len(blocks))) * 4
+        rng.shuffle(order)
+        stream = b"".join(blocks[i] for i in order)
+        fpc = eng.submit_fingerprint(chunker, stream)
+        eng.drain()
+        spans = fpc.result()
+        referenced = sum(ln for _off, ln, _fp in spans)
+        assert referenced == len(stream)
+        uniq = {}
+        for _off, ln, fp in spans:
+            uniq.setdefault(fp, ln)
+        assert referenced / max(1, sum(uniq.values())) > 2.0
+        off0, ln0, fp0 = spans[0]
+        assert fingerprint(stream[off0:off0 + ln0]) == fp0
+    finally:
+        eng.stop()
+        off.stop()

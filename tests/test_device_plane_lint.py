@@ -37,8 +37,6 @@ INDIRECT = {
         "vectorized health/summary passes, no per-object launches",
     "native/aot.py":         # IS the cache layer
         "CompileCache itself wraps jit for export",
-    "ops/gf_pallas.py":      # launched via ops.gf_jax wrappers
-        "kernel factory; GFLinear/GFEncodeDigest own the launch",
     "ops/gf_pallas2.py":     # launched via scrub/recovery engines
         "kernel factory; scrub.engine owns the launch",
 }

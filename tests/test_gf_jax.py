@@ -58,3 +58,11 @@ def test_gather_vs_bits_paths_agree():
                                   jnp.asarray(data), 5))
     assert np.array_equal(a, b)
     assert np.array_equal(a, gf.gf_matmul(coding, data))
+
+
+@pytest.mark.parametrize("backend", ["pallas-interp", "words"])
+def test_unknown_backend_is_refused(backend):
+    """A name outside GFLinear.BACKENDS raises instead of silently
+    running the XLA path."""
+    with pytest.raises(ValueError, match="unknown GFLinear backend"):
+        GFLinear(rs.reed_sol_van_matrix(4, 2), backend=backend)

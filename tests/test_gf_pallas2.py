@@ -1,11 +1,13 @@
-"""Byte-exactness of the v2 bit-sliced Pallas GF kernel (interpret
-mode on CPU; the real-TPU run is bench.py's pre-timing verify)."""
+"""Byte-exactness of the bit-sliced Pallas GF kernel (interpret mode
+on CPU).  On the TPU, `chip_smoke.py::phase_ec_kernels` compares the
+words kernel with the oracle and asserts `GFLinear`'s backend is
+``"pallas"``."""
 
 import numpy as np
 import pytest
 
 from ceph_tpu.ops import rs
-from ceph_tpu.ops.gf_jax import _bit_layout_matrix
+from ceph_tpu.ops.gf_jax import GFLinear, _bit_layout_matrix
 from ceph_tpu.ops.gf_pallas2 import (gf_expand_words, gf_matmul_pallas2,
                                      gf_matmul_planes)
 
@@ -94,18 +96,40 @@ def test_gflinear_pallas_backend_is_v2():
     assert np.array_equal(got, want)
 
 
-def test_v2_vs_v1_kernel():
-    """Old and new kernels agree bit-for-bit (the bench's roofline
-    comparison depends on both being the same map)."""
-    from ceph_tpu.ops.gf_pallas import gf_matmul_pallas
-    k, m = 8, 3
+@pytest.mark.parametrize("k,m", [(2, 1), (4, 2), (8, 3)])
+@pytest.mark.parametrize("batch,chunk", [((), 128), ((3,), 256),
+                                         ((2,), 200)])
+def test_pallas_matches_oracle(k, m, batch, chunk):
     coding = rs.reed_sol_van_matrix(k, m)
-    bitmat = _bit_layout_matrix(coding)
-    rng = np.random.default_rng(11)
-    data = rng.integers(0, 256, size=(4, k, 512), dtype=np.uint8)
-    v1 = np.asarray(gf_matmul_pallas(bitmat, data, m, interpret=True))
-    v2 = np.asarray(gf_matmul_pallas2(bitmat, data, m, interpret=True))
-    assert np.array_equal(v1, v2)
+    rng = np.random.default_rng(k * 100 + m)
+    data = rng.integers(0, 256, size=(*batch, k, chunk), dtype=np.uint8)
+    want = rs.encode_oracle(coding, data.reshape(-1, k, chunk)[0]) \
+        if batch else rs.encode_oracle(coding, data)
+    enc = GFLinear(coding, backend="pallas-interpret")
+    got = np.asarray(enc(data))
+    assert got.shape == (*batch, m, chunk)
+    ref = GFLinear(coding, backend="xla")
+    assert np.array_equal(got, np.asarray(ref(data)))
+    if not batch:
+        assert np.array_equal(got, want)
+
+
+def test_pallas_decode_roundtrip():
+    k, m = 4, 2
+    coding = rs.reed_sol_van_matrix(k, m)
+    rng = np.random.default_rng(7)
+    data = rng.integers(0, 256, size=(k, 384), dtype=np.uint8)
+    parity = np.asarray(GFLinear(coding,
+                                 backend="pallas-interpret")(data))
+    # erase two data chunks, decode from survivors
+    erasures = [0, 2]
+    dm = rs.decode_matrix(coding, k, erasures)
+    survivors = [i for i in range(k + m) if i not in erasures][:k]
+    stack = np.stack([data[i] if i < k else parity[i - k]
+                      for i in survivors])
+    dec = GFLinear(dm, backend="pallas-interpret")
+    out = np.asarray(dec(stack))
+    assert np.array_equal(out, data)
 
 
 # -- word-native path (round 5: the 10x production encode kernel) ----------

@@ -85,9 +85,9 @@ def test_simd_tiers_bit_exact_at_odd_sizes(tier, chunk):
 
 
 def test_encode_batch_matches_per_stripe_and_custom_matrix():
-    """encode_batch is the bench denominator: it must equal per-stripe
-    encode, and with a custom matrix it must apply exactly that map
-    (decode's inverse-submatrix multiply rides this path)."""
+    """encode_batch must equal per-stripe encode, and with a custom
+    matrix it must apply exactly that map (decode's inverse-submatrix
+    multiply rides this path)."""
     k, m = 8, 3
     ec = native.NativeEC(k, m)
     coding = rs.reed_sol_van_matrix(k, m)
