@@ -15,6 +15,8 @@ and ``ErasureCode.{h,cc}``; SURVEY.md §3.6):
   parity chunks, return the requested subset.
 - ``decode(want_to_read, chunks)`` — reconstruct the wanted chunks from
   any sufficient subset.
+- ``data_extents(size, extents)`` — which byte ranges of which data
+  shards hold a logical extent (a ranged read fetches only those).
 
 Data currency here is numpy uint8 arrays (host) — the TPU engine consumes
 batches of stripes; see `ceph_tpu.ec.jax_backend`.
@@ -141,6 +143,32 @@ class ErasureCodeInterface(abc.ABC):
         padded = np.zeros(chunk * self.k, dtype=np.uint8)
         padded[: buf.size] = buf
         return padded.reshape(self.k, chunk)
+
+    def data_extents(self, size: int, extents
+                     ) -> dict[int, tuple[int, int]] | None:
+        """Where logical bytes of a ``size``-byte object lie on the data
+        shards: each ``(off, length)`` extent, clipped to ``size``, maps
+        to ``{shard: (shard_off, shard_len)}``, one covering range per
+        touched shard.  Data chunk ``i`` is bytes ``[i * chunk, (i + 1)
+        * chunk)`` of the padded payload (``encode_prepare``), stored
+        raw and whole as shard ``i`` (``encode``).  None for a plugin
+        that lays its chunks out otherwise."""
+        cls = type(self)
+        if cls.encode_prepare is not ErasureCodeInterface.encode_prepare \
+                or cls.encode is not ErasureCodeInterface.encode:
+            return None
+        chunk = self.get_chunk_size(size)
+        out: dict[int, tuple[int, int]] = {}
+        for off, length in extents:
+            x, end = min(off, size), min(off + length, size)
+            while x < end:
+                s = x // chunk
+                lo, hi = x - s * chunk, min(end, (s + 1) * chunk) - s * chunk
+                if s in out:
+                    lo, hi = min(lo, out[s][0]), max(hi, sum(out[s]))
+                out[s] = (lo, hi - lo)
+                x = (s + 1) * chunk
+        return out
 
     def encode(self, want_to_encode: set[int],
                data: bytes | np.ndarray) -> dict[int, np.ndarray]:

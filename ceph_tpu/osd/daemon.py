@@ -100,6 +100,9 @@ def _build_osd_perf(name: str):
                    "reconstruct lane queue, flush, device, completion")
     b.add_u64_counter("op_r_degraded",
                       "client reads that needed a decode")
+    b.add_u64_counter("op_r_extent",
+                      "client reads served from the shard extents "
+                      "they touch")
     b.add_u64_counter("op_resent",
                       "client ops that arrived with retry_attempt > 0")
     b.add_u64_counter("subop", "replica/shard sub-operations")

@@ -112,9 +112,12 @@ class MOSDECSubOpWriteReply(JsonMessage):
 
 @register_message
 class MOSDECSubOpRead(JsonMessage):
-    """Primary → shard: read chunk extents (reference MOSDECSubOpRead)."""
+    """Primary → shard: read chunk extents (reference MOSDECSubOpRead).
+    ``off``/``len``: the byte range of the shard to return; None (an
+    older sender) means the whole shard."""
     TYPE = 49
-    FIELDS = ("tid", "pgid", "shard", "epoch", "oid", "attrs")
+    FIELDS = ("tid", "pgid", "shard", "epoch", "oid", "attrs", "off",
+              "len")
 
 
 @register_message

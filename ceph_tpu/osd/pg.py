@@ -3048,8 +3048,112 @@ class ECBackend(PGBackendBase):
             return simple
         if meta is None:
             raise KeyError(oid)
-        self._start_data_read(msg)
+        plan = self._extent_plan(msg, meta)
+        if plan is None:
+            self._start_data_read(msg)
+        else:
+            self._start_extent_read(msg, meta, plan)
         return None
+
+    def _extent_plan(self, msg: M.MOSDOp, meta: dict) -> dict | None:
+        """``{shard: (osd, off, len)}``: the shard byte ranges a client
+        read's ``read`` ops touch, each from its shard's assigned
+        holder.  None sends the read to the whole-shard gather: a read
+        of the whole object, a write in flight on it (its versions may
+        be mixed), a sealed payload, a touched holder down or missing
+        the object, or a plugin whose data chunk i is not shard i."""
+        oid = msg.oid
+        if oid in self._rmw or _meta_extra(meta):
+            return None
+        size = int(meta["size"])
+        extents = []
+        for op in msg.ops:
+            if op.get("op") == "read":
+                off, ln = int(op.get("off", 0)), op.get("len")
+                ln = max(0, size - off) if ln is None else int(ln)
+                if off < 0 or ln < 0:
+                    return None
+                extents.append((off, ln))
+        ec = self.engine
+        ranges = ec.data_extents(size, extents)
+        if ranges is None or ranges == ec.data_extents(size, [(0, size)]):
+            return None
+        pg = self.pg
+        avail = self._available_shards()
+        plan = {}
+        for s, (off, ln) in ranges.items():
+            o = avail.get(s)
+            if o is None or oid in pg.peer_missing.get(o, ()) or \
+                    (o == pg.daemon.whoami and oid in pg.missing):
+                return None
+            plan[s] = (o, off, ln)
+        return plan
+
+    def _start_extent_read(self, msg: M.MOSDOp, meta: dict, plan: dict):
+        """Fetch only the planned shard ranges: the local one from the
+        store, each remote one by a ranged ``MOSDECSubOpRead``."""
+        pg, daemon = self.pg, self.pg.daemon
+        self._read_tid += 1
+        tid = self._read_tid
+        self._reads[tid] = {"msg": msg, "oid": msg.oid, "meta": meta,
+                            "plan": plan, "chunks": {}}
+        pg.mark_tracked(msg, "sub_read_sent")
+        for s, (o, off, ln) in plan.items():
+            if o != daemon.whoami:
+                daemon.send_to_osd(o, M.MOSDECSubOpRead(
+                    tid=tid, pgid=str(pg.pgid), shard=s,
+                    epoch=daemon.osdmap.epoch, oid=msg.oid, attrs=True,
+                    off=off, len=ln))
+                continue
+            try:
+                got = daemon.store.read(pg.cid_for_shard(s), msg.oid,
+                                        off, ln)
+            except KeyError:
+                got = b""
+            if len(got) != ln:
+                del self._reads[tid]
+                self._start_data_read(msg)
+                return
+            self._reads[tid]["chunks"][s] = got
+        self._maybe_finish_extent_read(tid)
+
+    def _extent_read_reply(self, st: dict, msg: M.MOSDECSubOpReadReply):
+        pg = self.pg
+        if msg.rc == -5:
+            # the holder's whole shard failed its hinfo check
+            del self._reads[msg.tid]
+            pg._reply(st["msg"], -5, "chunk crc mismatch")
+            return
+        ln = st["plan"][msg.shard][2]
+        if msg.rc != 0 or len(msg.data) != ln or \
+                json.loads(msg.attrs["_"]).get("version") != \
+                st["meta"]["version"]:
+            # the holder lacks the object or holds another version (a
+            # stale stray): the whole-shard gather sorts that out, and
+            # these bytes are never served
+            del self._reads[msg.tid]
+            self._start_data_read(st["msg"])
+            return
+        st["chunks"][msg.shard] = msg.data
+        self._maybe_finish_extent_read(msg.tid)
+
+    def _maybe_finish_extent_read(self, tid: int):
+        st = self._reads.get(tid)
+        if st is None or len(st["chunks"]) < len(st["plan"]):
+            return
+        del self._reads[tid]
+        self.pg.mark_tracked(st["msg"], "sub_read_rec")
+        self.pg.daemon.perf.inc("op_r_extent")
+        meta, plan, got = st["meta"], st["plan"], st["chunks"]
+        size = int(meta["size"])
+
+        def read(off, end):
+            ranges = self.engine.data_extents(
+                size, [(off, (size if end is None else end) - off)])
+            return b"".join(got[s][a - plan[s][1]:a - plan[s][1] + n]
+                            for s, (a, n) in sorted(ranges.items()))
+
+        self._reply_read(st["msg"], meta, read)
 
     def _available_shards(self) -> dict[int, int]:
         """shard → osd by acting position, live members only."""
@@ -3202,6 +3306,14 @@ class ECBackend(PGBackendBase):
                 rc = 0
             except KeyError:
                 data, meta, rc = b"", b"{}", -2
+            if rc == 0 and msg.off is not None:
+                # the primary sees only the range, so the holder checks
+                # its whole shard against hinfo before it answers
+                hinfo = json.loads(meta).get("hinfo")
+                if hinfo is not None and crc32c(data) != hinfo:
+                    data, rc = b"", -5
+                else:
+                    data = data[msg.off:msg.off + msg.len]
             daemon.send_to_osd(pg.primary, M.MOSDECSubOpReadReply(
                 tid=msg.tid, pgid=msg.pgid, shard=msg.shard,
                 epoch=daemon.osdmap.epoch, rc=rc, data=data,
@@ -3227,6 +3339,9 @@ class ECBackend(PGBackendBase):
     def handle_sub_read_reply(self, msg: M.MOSDECSubOpReadReply):
         st = self._reads.get(msg.tid)
         if st is None:
+            return
+        if "plan" in st:
+            self._extent_read_reply(st, msg)
             return
         if msg.rc != 0:
             # the assigned member may simply not hold this object's
@@ -3480,17 +3595,22 @@ class ECBackend(PGBackendBase):
                 [decoded[i] for i in sorted(st["want"])]
             ).tobytes()[:stored]
             payload = self.pg.unseal_payload(raw, meta)
+        self._reply_read(st["msg"], meta,
+                         lambda off, end: payload[off:end])
+
+    def _reply_read(self, msg: M.MOSDOp, meta: dict, read):
+        """Answer a client read; ``read(off, end)`` gives a ``read``
+        op's logical bytes (``end`` None: to the object's end)."""
         results = []
-        msg = st["msg"]
         for op in msg.ops:
             kind = op.get("op")
             if kind == "read":
                 off = int(op.get("off", 0))
                 ln = op.get("len")
-                end = len(payload) if ln is None else off + int(ln)
-                results.append({"data": payload[off:end]})
+                results.append({"data": read(
+                    off, None if ln is None else off + int(ln))})
             elif kind == "stat":
-                results.append({"size": size,
+                results.append({"size": int(meta.get("size", 0)),
                                 "version": tuple(meta["version"])})
             else:
                 # non-data ops re-run locally for the final answer

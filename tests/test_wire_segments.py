@@ -266,3 +266,30 @@ class TestLibradosEdge:
         # the primary's copy of the payload and the remote shards
         assert recv_data() - before >= len(data)
         assert io.read("bulk") == data
+
+    def test_sub_read_without_a_range_is_served_whole(self, ec_io):
+        c, io = ec_io
+        io.write_full("whole", os.urandom(64 * KIB))   # 32 KiB shards
+        m = io.rados.objecter.osdmap
+        pgid = m.raw_pg_to_pg(m.object_locator_to_pg("whole", io.pool_id))
+        holder = c.osds[m.pg_to_up_acting_osds(pgid)[2][1]]
+        ranged = M.MOSDECSubOpRead(tid=1, pgid=str(pgid), shard=1,
+                                   epoch=holder.osdmap.epoch, oid="whole",
+                                   attrs=True, off=5, len=7)
+        assert (_roundtrip(ranged).off, _roundtrip(ranged).len) == (5, 7)
+        # an older sender's MOSDECSubOpRead has no range fields
+        ranged.FIELDS = tuple(f for f in M.MOSDECSubOpRead.FIELDS
+                              if f not in ("off", "len"))
+        old = _roundtrip(ranged)
+        assert old.off is None and old.len is None
+        replies = []
+        holder.send_to_osd = lambda _o, msg: replies.append(msg)
+        try:
+            with holder.lock:
+                holder.pgs[pgid].backend.handle_sub_read(old)
+                stored = holder.store.read(f"{pgid}s1", "whole")
+        finally:
+            del holder.send_to_osd
+        (reply,) = replies
+        assert reply.rc == 0 and len(stored) == 32 * KIB
+        assert reply.data == stored
